@@ -36,8 +36,8 @@ def run():
     vq, vs = kv_quant.quantize_kv(vc)
     k_pool = jnp.swapaxes(kq, 0, 1).reshape(Hkv, B * nb, bs_blk, hd)
     v_pool = jnp.swapaxes(vq, 0, 1).reshape(Hkv, B * nb, bs_blk, hd)
-    ks_pool = jnp.swapaxes(ks, 0, 1).reshape(Hkv, B * nb, bs_blk)
-    vs_pool = jnp.swapaxes(vs, 0, 1).reshape(Hkv, B * nb, bs_blk)
+    ks_pool = jnp.swapaxes(ks, 0, 1).reshape(Hkv, B * nb, 1, bs_blk)
+    vs_pool = jnp.swapaxes(vs, 0, 1).reshape(Hkv, B * nb, 1, bs_blk)
     bt = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
     t_int8 = time_call(
         lambda: ref.paged_decode_attention_int8_ref(

@@ -37,22 +37,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 promotes shard_map out of experimental
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover — older jax in the container
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core import combine as C
 
 
 def _shard_map_norep(fn, **kw):
     """shard_map without the replication checker: pallas_call has no
-    replication rule, and the paged backends may run the kernel in-shard.
-    jax >= 0.7 renamed check_rep to check_vma."""
-    try:
-        return _shard_map(fn, check_rep=False, **kw)
-    except TypeError:  # pragma: no cover — newer jax
-        return _shard_map(fn, check_vma=False, **kw)
+    replication rule, and the paged backends may run the kernel in-shard."""
+    return jax.shard_map(fn, check_vma=False, **kw)
 
 
 def _masked_partial(q, k_cache, v_cache, valid, logit_softcap=0.0):
@@ -105,7 +96,7 @@ def seq_parallel_decode_attention(mesh: Mesh, axis: str, q, k_cache, v_cache,
         part = _masked_partial(q, kc, vc, valid, logit_softcap)
         return C.finalize(C.psum_combine(part, axis)).astype(q.dtype)
 
-    return _shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(batch_axis, None, None), P(batch_axis, axis, None, None),
                   P(batch_axis, axis, None, None), bspec),
@@ -140,7 +131,7 @@ def head_parallel_decode_attention(mesh: Mesh, axis: str, q, k_cache, v_cache,
         part = _masked_partial(q, kc, vc, valid, logit_softcap)
         return C.finalize(part).astype(q.dtype)
 
-    return _shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(batch_axis, axis, None), P(batch_axis, None, axis, None),
                   P(batch_axis, None, axis, None), bspec),
@@ -166,7 +157,7 @@ def _paged_shard_attend(q, kp, vp, bt, clen, *, sliding_window: int,
     q: (B, H_local, hd); kp/vp: (Hkv_local, NB, bs, hd); bt: (B, nb);
     clen: (B,). 'pallas' runs the paged flash-decode kernel; 'jnp' its
     head-major gather reference (the CPU data path). Int8 pool slices
-    carry their (Hkv_local, NB, bs) scale slices; dequant fuses in-shard
+    carry their (Hkv_local, NB, 1, bs) scale slices; dequant fuses in-shard
     inside the backend (no dense dequantized slab per device either)."""
     from repro.kernels.paged_decode_attention import (paged_decode_attention,
                                                      paged_decode_attention_jnp)
@@ -197,7 +188,7 @@ def head_parallel_paged_decode_attention(mesh: Mesh, axis: str, q, k_pool,
     and lengths are replicated scalars. Each device runs the paged kernel
     (or its jnp reference) over its head slice in place — no dense view, no
     combine (heads are independent). Requires Hkv % mesh.shape[axis] == 0
-    (paper §5). Int8 pools: the (Hkv, NB, bs) scale pools shard with the
+    (paper §5). Int8 pools: the (Hkv, NB, 1, bs) scale pools shard with the
     same head axis as the value pools (scales-follow-blocks)."""
     Hkv = k_pool.shape[0]
     n = mesh.shape[axis]
@@ -220,7 +211,7 @@ def head_parallel_paged_decode_attention(mesh: Mesh, axis: str, q, k_pool,
                 P(axis, None, None, None), btspec, bspec]
     if k_scale is not None:
         operands += [k_scale, v_scale]
-        in_specs += [P(axis, None, None)] * 2
+        in_specs += [P(axis, None, None, None)] * 2
     return _shard_map_norep(
         shard_fn, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=P(batch_axis, axis, None),
@@ -253,7 +244,7 @@ def request_parallel_paged_decode_attention(mesh: Mesh, axis: str, q, k_pool,
                 P(None, None, None, None), P(axis, None), P(axis)]
     if k_scale is not None:
         operands += [k_scale, v_scale]
-        in_specs += [P(None, None, None)] * 2
+        in_specs += [P(None, None, None, None)] * 2
     return _shard_map_norep(
         shard_fn, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=P(axis, None, None),
@@ -319,7 +310,7 @@ def block_parallel_paged_decode_attention(mesh: Mesh, axis: str, q, k_pool,
                 P(axis, None, None), P(axis, None, None), P()]
     if k_scale is not None:
         operands += [k_scale, v_scale]
-        in_specs += [P(None, axis, None)] * 2
+        in_specs += [P(None, axis, None, None)] * 2
     return _shard_map_norep(
         shard_fn, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=P(),

@@ -1,9 +1,11 @@
 """jit'd dispatch wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run with interpret=True; on TPU the same
-call sites compile the Mosaic kernels. ``repro.models.attention`` registers
-the decode kernel as the "pallas" backend so any model's serve path can
-switch with ``DisaggConfig.decode_backend``.
+Each call picks the mode from the default backend (:func:`interpret_mode`):
+on the CPU the kernels run with interpret=True, on the TPU the same call
+sites compile the Mosaic kernels. Importing this module touches no backend.
+``repro.models.attention`` registers the decode kernel as the "pallas"
+backend so any model's serve path can switch with
+``EngineConfig.decode_backend``.
 """
 from __future__ import annotations
 
@@ -17,7 +19,17 @@ from repro.kernels import paged_prefill_attention as _ppa
 from repro.kernels import rwkv6_scan as _rw
 from repro.kernels import ssm_scan as _ssm
 
-_INTERPRET = jax.default_backend() == "cpu"
+
+def interpret_mode() -> bool:
+    """True on the CPU (Pallas interpreter), False on the TPU (Mosaic).
+    Any other platform is an error: the kernels are written for the TPU,
+    and silently interpreting them elsewhere would hide that."""
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run on the TPU, or interpreted on the CPU; the "
+            f"default backend is {platform!r}")
+    return platform == "cpu"
 
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, block_k: int = 512,
@@ -30,7 +42,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, block_k: int = 512,
     out = _da.decode_attention(qg, k_cache, v_cache, cache_len,
                                block_k=block_k, sliding_window=sliding_window,
                                logit_softcap=logit_softcap,
-                               interpret=_INTERPRET)
+                               interpret=interpret_mode())
     return out.reshape(B, H, hd)
 
 
@@ -57,18 +69,19 @@ def paged_prefill_chunk_attention(q, k_pool, v_pool, block_table,
     if backend == "pallas":
         return _ppa.paged_prefill_chunk_attention(
             q, k_pool, v_pool, block_table, k_chunk, v_chunk,
-            interpret=_INTERPRET, **kw)
+            interpret=interpret_mode(), **kw)
     return _ppa.paged_prefill_chunk_attention_jnp(
         q, k_pool, v_pool, block_table, k_chunk, v_chunk, **kw)
 
 
 def rwkv6_scan(r, k, v, w, u, *, chunk: int = 128):
-    return _rw.rwkv6_scan(r, k, v, w, u, chunk=chunk, interpret=_INTERPRET)
+    return _rw.rwkv6_scan(r, k, v, w, u, chunk=chunk,
+                          interpret=interpret_mode())
 
 
 def ssm_scan(x, B_in, C_in, decay, *, chunk: int = 128):
     return _ssm.ssm_scan(x, B_in, C_in, decay, chunk=chunk,
-                         interpret=_INTERPRET)
+                         interpret=interpret_mode())
 
 
 # --- register the Pallas decode backend with the model layer --------------
@@ -109,7 +122,7 @@ def _pallas_decode_partial_backend(q, k_cache, v_cache, cache_len, *,
     o, l, m = _da.decode_attention(
         qg, k_cache, v_cache, clen, sliding_window=sw,
         attention_sinks=sinks, logit_softcap=logit_softcap,
-        interpret=_INTERPRET, return_partials=True)
+        interpret=interpret_mode(), return_partials=True)
     return _triple_to_partial(o, l, m, B, H, hd)
 
 
@@ -132,7 +145,7 @@ def _pallas_paged_decode_partial_backend(q, k_pool, v_pool, block_tables,
         qg, k_pool, v_pool, block_tables, clen,
         k_scale=k_scale, v_scale=v_scale, sliding_window=sw,
         attention_sinks=sinks, logit_softcap=logit_softcap,
-        interpret=_INTERPRET, return_partials=True)
+        interpret=interpret_mode(), return_partials=True)
     return _triple_to_partial(o, l, m, B, H, hd)
 
 
@@ -156,7 +169,7 @@ def pallas_paged_decode_partial_pos(q, k_pool, v_pool, block_tables,
         block_positions=block_positions,
         k_scale=k_scale, v_scale=v_scale, sliding_window=sw,
         attention_sinks=sinks, logit_softcap=logit_softcap,
-        interpret=_INTERPRET, return_partials=True)
+        interpret=interpret_mode(), return_partials=True)
     return _triple_to_partial(o, l, m, B, H, hd)
 
 

@@ -52,6 +52,29 @@ NEG_INF = -1e30
 POS_PAD = 1 << 30
 
 
+def _block_masks(base, cache_len, *, block_size: int, sliding_window: int,
+                 attention_sinks: int):
+    """Validity of one pool block's rows, at global positions ``base +
+    [0, block_size)``: ``base`` is the prefetched per-slot base
+    (slot·block_size for contiguous tables; arbitrary — including POS_PAD —
+    for block-sharded ones). Returned twice, as a ``(block_size, 1)`` column
+    that masks the v tile and a ``(1, block_size)`` row that masks the
+    scores: each comes from its own 2-D iota, because Mosaic cannot reshape
+    a 1-D lane vector into a column."""
+    def valid(pos):
+        ok = pos < cache_len
+        if sliding_window > 0:
+            in_window = pos >= (cache_len - sliding_window)
+            if attention_sinks > 0:  # StreamingLLM sinks stay attendable
+                in_window |= pos < attention_sinks
+            ok &= in_window
+        return ok
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
+    return valid(base + col), valid(base + row)
+
+
 def _paged_decode_kernel(bt_ref, bp_ref, len_ref, q_ref, k_ref, v_ref,
                          o_ref, lo_ref, mo_ref,
                          acc_ref, m_ref, l_ref, *,
@@ -69,22 +92,13 @@ def _paged_decode_kernel(bt_ref, bp_ref, len_ref, q_ref, k_ref, v_ref,
     q = q_ref[0, 0].astype(jnp.float32)          # (G, hd)
     k = k_ref[0, 0].astype(jnp.float32)          # (block_size, hd) pool block
     v = v_ref[0, 0].astype(jnp.float32)
-    cache_len = len_ref[b]
 
-    # global sequence positions of this pool block's rows: the prefetched
-    # per-slot base (slot·block_size for contiguous tables; arbitrary —
-    # including POS_PAD — for block-sharded ones)
-    pos = bp_ref[b, kb] + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)[0]        # (block_size,)
-    row_valid = pos < cache_len
-    if sliding_window > 0:
-        in_window = pos >= (cache_len - sliding_window)
-        if attention_sinks > 0:  # StreamingLLM sinks stay attendable
-            in_window |= pos < attention_sinks
-        row_valid &= in_window
     # stale pool blocks may hold anything — zero v under the mask so the
     # weighted sum can never see Inf/NaN through a 0-weight column
-    v = jnp.where(row_valid[:, None], v, 0.0)
+    v_mask, s_mask = _block_masks(
+        bp_ref[b, kb], len_ref[b], block_size=block_size,
+        sliding_window=sliding_window, attention_sinks=attention_sinks)
+    v = jnp.where(v_mask, v, 0.0)
 
     hd = q.shape[-1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
@@ -92,7 +106,7 @@ def _paged_decode_kernel(bt_ref, bp_ref, len_ref, q_ref, k_ref, v_ref,
                             preferred_element_type=jnp.float32)  # (G, bs)
     if logit_softcap > 0.0:
         s = logit_softcap * jnp.tanh(s / logit_softcap)
-    valid = jnp.broadcast_to(row_valid[None, :], s.shape)
+    valid = jnp.broadcast_to(s_mask, s.shape)
     s = jnp.where(valid, s, NEG_INF)
 
     # paper §4.2.2 combine: rebase running (acc, l) onto the new max
@@ -123,7 +137,7 @@ def _paged_decode_kernel_int8(bt_ref, bp_ref, len_ref, q_ref, k_ref, v_ref,
                               attention_sinks: int, logit_softcap: float,
                               nb: int):
     """int8-pool variant of :func:`_paged_decode_kernel`: k/v tiles arrive
-    quantized with per-token fp32 scale tiles ``(block_size,)`` riding the
+    quantized with per-token fp32 scale tiles ``(1, block_size)`` riding the
     same block-table walk, and dequantization fuses into the score / PV
     products as ONE broadcast multiply per (G, block_size) tile — the k
     scale folds into ``s`` right after the QK product (before softcap, where
@@ -142,31 +156,25 @@ def _paged_decode_kernel_int8(bt_ref, bp_ref, len_ref, q_ref, k_ref, v_ref,
     q = q_ref[0, 0].astype(jnp.float32)          # (G, hd)
     k = k_ref[0, 0].astype(jnp.float32)          # (block_size, hd) int8->f32
     v = v_ref[0, 0].astype(jnp.float32)
-    ks = ks_ref[0, 0]                            # (block_size,) fp32 scales
+    ks = ks_ref[0, 0]                            # (1, block_size) fp32 scales
     vs = vs_ref[0, 0]
-    cache_len = len_ref[b]
 
-    pos = bp_ref[b, kb] + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)[0]        # (block_size,)
-    row_valid = pos < cache_len
-    if sliding_window > 0:
-        in_window = pos >= (cache_len - sliding_window)
-        if attention_sinks > 0:
-            in_window |= pos < attention_sinks
-        row_valid &= in_window
     # int8 loads are always finite, but stale scales are arbitrary (finite)
     # numbers — zero v under the mask exactly like the bf16 kernel so the
     # masked columns contribute exact zeros through the zeroed p
-    v = jnp.where(row_valid[:, None], v, 0.0)
+    v_mask, s_mask = _block_masks(
+        bp_ref[b, kb], len_ref[b], block_size=block_size,
+        sliding_window=sliding_window, attention_sinks=attention_sinks)
+    v = jnp.where(v_mask, v, 0.0)
 
     hd = q.shape[-1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, bs)
-    s = s * ks[None, :]                          # fused k-dequant (pre-cap)
+    s = s * ks                                   # fused k-dequant (pre-cap)
     if logit_softcap > 0.0:
         s = logit_softcap * jnp.tanh(s / logit_softcap)
-    valid = jnp.broadcast_to(row_valid[None, :], s.shape)
+    valid = jnp.broadcast_to(s_mask, s.shape)
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -177,7 +185,7 @@ def _paged_decode_kernel_int8(bt_ref, bp_ref, len_ref, q_ref, k_ref, v_ref,
     p = jnp.where(valid, p, 0.0)
     l_new = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p * vs[None, :], v, (((1,), (0,)), ((), ())),  # fused v-dequant
+        p * vs, v, (((1,), (0,)), ((), ())),  # fused v-dequant
         preferred_element_type=jnp.float32)
     m_ref[...] = m_new
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -213,11 +221,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len, *,
     live tokens. block_positions: optional (B, nb) int32 global base position
     per table slot (defaults to the contiguous slot·block_size; block-sharded
     callers pass their shard's true positions, POS_PAD on foreign slots).
-    k_scale/v_scale: optional (Hkv, num_blocks, block_size) fp32 per-token
-    scale pools for an int8 k_pool/v_pool — when given, the int8 kernel
-    variant streams the scale tiles through the SAME block-table walk and
-    fuses dequantization into the score/PV products (no dense dequantized
-    slab, in VMEM or HBM).
+    k_scale/v_scale: optional (Hkv, num_blocks, 1, block_size) fp32
+    per-token scale pools for an int8 k_pool/v_pool — when given, the int8
+    kernel variant streams the scale tiles through the SAME block-table walk
+    and fuses dequantization into the score/PV products (no dense
+    dequantized slab, in VMEM or HBM).
     Returns (B, Hkv, G, hd), or the (o, l, m) §4.2.2 triple over the cached
     subset when return_partials — mergeable with other partials (e.g. across
     the pool mesh axis via ``core.combine.psum_combine``).
@@ -241,9 +249,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len, *,
         logit_softcap=logit_softcap, nb=nb)
     kv_spec = pl.BlockSpec((1, 1, block_size, hd),
                            lambda b, h, kb, bt, bp, ln: (h, bt[b, kb], 0, 0))
-    # scale tiles ride the same prefetched table walk as their value tiles
-    scale_spec = pl.BlockSpec((1, 1, block_size),
-                              lambda b, h, kb, bt, bp, ln: (h, bt[b, kb], 0))
+    # scale tiles ride the same prefetched table walk as their value tiles;
+    # each is a whole (1, block_size) row, as Mosaic's tiling rule asks
+    scale_spec = pl.BlockSpec((1, 1, 1, block_size),
+                              lambda b, h, kb, bt, bp, ln: (h, bt[b, kb], 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, G, hd),
                      lambda b, h, kb, bt, bp, ln: (b, h, 0, 0)),
@@ -296,12 +305,12 @@ def paged_gather_dense(k_pool, v_pool, block_tables):
 
 
 def paged_gather_scales(scale_pool, block_tables):
-    """Block-table gather of a (Hkv, num_blocks, bs) scale pool into the
+    """Block-table gather of a (Hkv, num_blocks, 1, bs) scale pool into the
     dense (B, Hkv, nb·bs) per-token view the dense int8 references fold into
     the score/PV einsums — reference data path only."""
-    Hkv, _, bs = scale_pool.shape
+    Hkv, _, _, bs = scale_pool.shape
     B, nb = block_tables.shape
-    s = jnp.swapaxes(scale_pool[:, block_tables], 0, 1)  # (B, Hkv, nb, bs)
+    s = jnp.swapaxes(scale_pool[:, block_tables], 0, 1)  # (B, Hkv, nb, 1, bs)
     return s.reshape(B, Hkv, nb * bs)
 
 

@@ -51,6 +51,33 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _chunk_masks(kb, rows: int, *, block_size: int, chunk_len: int,
+                 prefix_blocks: int, total_len: int, sliding_window: int,
+                 attention_sinks: int):
+    """Masks of grid step ``kb``: a ``(block_size, 1)`` column of key rows
+    that exist (chunk padding excluded), for the v tile, and the
+    ``(rows, block_size)`` score mask. The prefix is the sequence's
+    contiguous first P tokens and the chunk follows immediately, so every
+    step's key base is kb·block_size; query row r = g·C + t holds chunk
+    token t at P + t. Both masks come from 2-D iotas: Mosaic cannot reshape
+    a 1-D lane vector into a column."""
+    base = kb * block_size
+    v_mask = (base + jax.lax.broadcasted_iota(
+        jnp.int32, (block_size, 1), 0)) < total_len
+    pos_k = base + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
+    pos_q = (prefix_blocks * block_size +
+             jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0)
+             % chunk_len)                         # (rows, block_size)
+    valid = (pos_k < total_len) & (pos_k <= pos_q)
+    if sliding_window > 0:
+        in_window = pos_k > (pos_q - sliding_window)
+        if attention_sinks > 0:   # StreamingLLM sinks stay attendable
+            in_window |= jnp.broadcast_to(pos_k < attention_sinks,
+                                          valid.shape)
+        valid &= in_window
+    return v_mask, valid
+
+
 def _paged_prefill_chunk_kernel(bt_ref, q_ref, k_ref, v_ref, kc_ref, vc_ref,
                                 o_ref, acc_ref, m_ref, l_ref, *,
                                 block_size: int, chunk_len: int,
@@ -77,26 +104,13 @@ def _paged_prefill_chunk_kernel(bt_ref, q_ref, k_ref, v_ref, kc_ref, vc_ref,
     k = jnp.where(is_prefix, k_pool_blk, k_chk_blk)
     v = jnp.where(is_prefix, v_pool_blk, v_chk_blk)
 
-    # key positions: prefix is the sequence's contiguous first P tokens and
-    # the chunk follows immediately, so every step's base is kb·block_size
-    pos_k = kb * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)[0]         # (block_size,)
-    col_valid = pos_k < total_len                 # kills chunk padding
-    # query positions: row r = g·C + t holds chunk token t at P + t
-    pos_q = (prefix_blocks * block_size +
-             jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0)
-             % chunk_len)                         # (rows, block_size)
-
-    valid = col_valid[None, :] & (pos_k[None, :] <= pos_q)
-    if sliding_window > 0:
-        in_window = pos_k[None, :] > (pos_q - sliding_window)
-        if attention_sinks > 0:   # StreamingLLM sinks stay attendable
-            in_window |= jnp.broadcast_to(pos_k[None, :] < attention_sinks,
-                                          valid.shape)
-        valid &= in_window
     # padded chunk rows may hold anything — zero v under the column mask so
     # the weighted sum can never see Inf/NaN through a 0-weight column
-    v = jnp.where(col_valid[:, None], v, 0.0)
+    v_mask, valid = _chunk_masks(
+        kb, rows, block_size=block_size, chunk_len=chunk_len,
+        prefix_blocks=prefix_blocks, total_len=total_len,
+        sliding_window=sliding_window, attention_sinks=attention_sinks)
+    v = jnp.where(v_mask, v, 0.0)
 
     hd = q.shape[-1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
@@ -158,31 +172,21 @@ def _paged_prefill_chunk_kernel_int8(bt_ref, q_ref, k_ref, v_ref,
     v_chk_blk = vc_ref[0, 0].astype(jnp.float32)
     k = jnp.where(is_prefix, k_pool_blk, k_chk_blk)
     v = jnp.where(is_prefix, v_pool_blk, v_chk_blk)
-    one = jnp.ones((block_size,), jnp.float32)    # chunk steps: ×1.0 exact
+    one = jnp.ones((1, block_size), jnp.float32)  # chunk steps: ×1.0 exact
     ks = jnp.where(is_prefix, ks_ref[0, 0], one)
     vs = jnp.where(is_prefix, vs_ref[0, 0], one)
 
-    pos_k = kb * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)[0]         # (block_size,)
-    col_valid = pos_k < total_len
-    pos_q = (prefix_blocks * block_size +
-             jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0)
-             % chunk_len)                         # (rows, block_size)
-
-    valid = col_valid[None, :] & (pos_k[None, :] <= pos_q)
-    if sliding_window > 0:
-        in_window = pos_k[None, :] > (pos_q - sliding_window)
-        if attention_sinks > 0:
-            in_window |= jnp.broadcast_to(pos_k[None, :] < attention_sinks,
-                                          valid.shape)
-        valid &= in_window
-    v = jnp.where(col_valid[:, None], v, 0.0)
+    v_mask, valid = _chunk_masks(
+        kb, rows, block_size=block_size, chunk_len=chunk_len,
+        prefix_blocks=prefix_blocks, total_len=total_len,
+        sliding_window=sliding_window, attention_sinks=attention_sinks)
+    v = jnp.where(v_mask, v, 0.0)
 
     hd = q.shape[-1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (rows, bs)
-    s = s * ks[None, :]                           # fused k-dequant (pre-cap)
+    s = s * ks                                    # fused k-dequant (pre-cap)
     if logit_softcap > 0.0:
         s = logit_softcap * jnp.tanh(s / logit_softcap)
     s = jnp.where(valid, s, NEG_INF)
@@ -195,7 +199,7 @@ def _paged_prefill_chunk_kernel_int8(bt_ref, q_ref, k_ref, v_ref,
     p = jnp.where(valid, p, 0.0)
     l_new = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p * vs[None, :], v, (((1,), (0,)), ((), ())),  # fused v-dequant
+        p * vs, v, (((1,), (0,)), ((), ())),  # fused v-dequant
         preferred_element_type=jnp.float32)
     m_ref[...] = m_new
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -222,7 +226,7 @@ def paged_prefill_chunk_attention(q, k_pool, v_pool, block_table,
     pool ids of the sequence's ALREADY-WRITTEN first nb blocks (the
     block-aligned prefix); k_chunk/v_chunk: (C, Hkv, hd) — this chunk's
     freshly projected K/V (not yet in the pool). k_scale/v_scale: optional
-    (Hkv, num_blocks, block_size) fp32 per-token scale pools for an int8
+    (Hkv, num_blocks, 1, block_size) fp32 per-token scale pools for an int8
     k_pool/v_pool — the int8 kernel variant fuses dequant into the
     score/PV products; the chunk's own K/V stay full precision.
     Returns (C, H, hd).
@@ -263,10 +267,11 @@ def paged_prefill_chunk_attention(q, k_pool, v_pool, block_table,
     pool_spec = pl.BlockSpec(
         (1, 1, block_size, hd),
         lambda h, kb, bt: (h, bt[jnp.minimum(kb, clamp)], 0, 0))
-    # scale tiles ride the same clamped table walk as their value tiles
+    # scale tiles ride the same clamped table walk as their value tiles;
+    # each is a whole (1, block_size) row, as Mosaic's tiling rule asks
     scale_spec = pl.BlockSpec(
-        (1, 1, block_size),
-        lambda h, kb, bt: (h, bt[jnp.minimum(kb, clamp)], 0))
+        (1, 1, 1, block_size),
+        lambda h, kb, bt: (h, bt[jnp.minimum(kb, clamp)], 0, 0))
     chunk_spec = pl.BlockSpec(
         (1, 1, block_size, hd),
         lambda h, kb, bt: (h, jnp.maximum(kb - nb, 0), 0, 0))
@@ -315,11 +320,11 @@ def gather_prefix_dense(k_pool, v_pool, block_table):
 
 
 def gather_prefix_scales(scale_pool, block_table):
-    """Block-table gather of a (Hkv, num_blocks, bs) scale pool into the
+    """Block-table gather of a (Hkv, num_blocks, 1, bs) scale pool into the
     seq-major (P, Hkv) per-token view — reference data path only."""
-    Hkv, _, bs = scale_pool.shape
+    Hkv, _, _, bs = scale_pool.shape
     nb = block_table.shape[0]
-    s = scale_pool[:, block_table]            # (Hkv, nb, bs)
+    s = scale_pool[:, block_table]            # (Hkv, nb, 1, bs)
     return s.reshape(Hkv, nb * bs).T          # (P, Hkv)
 
 

@@ -72,7 +72,7 @@ def paged_decode_attention_int8_ref(q, k_pool, v_pool, k_scale, v_scale,
     XLA ops, so the contract is ``assert_array_equal``, not allclose.
 
     q: (B, Hkv, G, hd); k_pool/v_pool: int8 (Hkv, num_blocks, bs, hd);
-    k_scale/v_scale: fp32 (Hkv, num_blocks, bs); block_tables: (B, nb).
+    k_scale/v_scale: fp32 (Hkv, num_blocks, 1, bs); block_tables: (B, nb).
     Test-scale only (python grid loop)."""
     from repro.kernels.paged_decode_attention import (NEG_INF,
                                                       default_block_positions)
@@ -95,8 +95,8 @@ def paged_decode_attention_int8_ref(q, k_pool, v_pool, k_scale, v_scale,
                 blk = block_tables[b, kb]
                 k = k_pool[h, blk].astype(jnp.float32)        # (bs, hd)
                 v = v_pool[h, blk].astype(jnp.float32)
-                ks = k_scale[h, blk]                          # (bs,)
-                vs = v_scale[h, blk]
+                ks = k_scale[h, blk, 0]                       # (bs,)
+                vs = v_scale[h, blk, 0]
                 pos = block_positions[b, kb] + jax.lax.broadcasted_iota(
                     jnp.int32, (1, bs), 1)[0]
                 row_valid = pos < cache_len[b]
@@ -173,7 +173,7 @@ def paged_prefill_chunk_attention_int8_ref(q, k_pool, v_pool,
                 blk = block_table[kb]
                 k = k_pool[h, blk].astype(jnp.float32)
                 v = v_pool[h, blk].astype(jnp.float32)
-                ks, vs = k_scale[h, blk], v_scale[h, blk]
+                ks, vs = k_scale[h, blk, 0], v_scale[h, blk, 0]
             else:
                 k = kc[h, kb - nb].astype(jnp.float32)
                 v = vc[h, kb - nb].astype(jnp.float32)

@@ -29,15 +29,6 @@ import time
 import traceback
 
 
-
-def _cost_dict(compiled):
-    """compiled.cost_analysis() compat: jax 0.4.x returns a one-dict-per-
-    program list, jax >= 0.5 a flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # pragma: no cover — older jax
-        ca = ca[0] if ca else {}
-    return ca
-
 def run_one(arch: str, shape: str, *, multi_pod: bool, mode: str,
             out_dir: str, attention_partition: str = "auto",
             overrides=None, tag: str = "") -> dict:
@@ -83,7 +74,7 @@ def run_one(arch: str, shape: str, *, multi_pod: bool, mode: str,
                        if v is not None)
         record["memory"]["per_chip_total"] = per_chip
         record["memory"]["fits_v5e_16g"] = bool(per_chip <= 16 * (1 << 30))
-        ca = _cost_dict(compiled)
+        ca = compiled.cost_analysis()
         record["cost_natural"] = {"flops": ca.get("flops"),
                                   "bytes": ca.get("bytes accessed")}
         coll = hlo_analysis.collective_bytes(compiled.as_text())
@@ -119,7 +110,7 @@ def run_one(arch: str, shape: str, *, multi_pod: bool, mode: str,
                              out_shardings=sp.out_shardings,
                              donate_argnums=sp.donate)
                 comp = jt.lower(*sp.args).compile()
-                c = _cost_dict(comp)
+                c = comp.cost_analysis()
                 cb = hlo_analysis.collective_bytes(comp.as_text())
                 return (float(c.get("flops", 0.0)),
                         float(c.get("bytes accessed", 0.0)), cb, sp)
@@ -134,7 +125,7 @@ def run_one(arch: str, shape: str, *, multi_pod: bool, mode: str,
             record["cost_method"] = f"extrapolated_u{unit}"
         else:
             spec, lowered, compiled = lower_compile(unrolled=True)
-            ca = _cost_dict(compiled)
+            ca = compiled.cost_analysis()
             coll = hlo_analysis.collective_bytes(compiled.as_text())
             record["cost_method"] = "unrolled_full"
         # corrections always use the FULL layer count

@@ -8,15 +8,6 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kw(n: int) -> dict:
-    """jax >= 0.5 takes explicit axis_types; older jax (this container's
-    0.4.x) has no AxisType and defaults every axis to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:  # pragma: no cover — older jax
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False, attn_pool: int = 0):
     """Single pod: (data=16, model=16) = 256 chips (TPU v5e target).
     Multi-pod: (pod=2, data=16, model=16) = 512 chips; the `pod` axis rides
@@ -39,12 +30,14 @@ def make_production_mesh(*, multi_pod: bool = False, attn_pool: int = 0):
     else:
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for CPU tests (requires host-device override)."""
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(shape)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_test_attn_pool_mesh(n_pool: int = 4, model: int = 2):

@@ -32,9 +32,30 @@ the stats summary always prints.
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
+
+# <checkout>/.jax_cache (this file is <checkout>/src/repro/launch/serve.py)
+COMPILE_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 
-def main() -> None:
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first compile
+    and return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
+    JAX reads it itself and nothing is set here; otherwise the cache lives
+    at the fixed ``<checkout>/.jax_cache``, so a later run in the same
+    checkout finds what an earlier one compiled (the path is part of what
+    the cache is keyed on, so it must not vary between runs)."""
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--mode", default="engine",
@@ -112,24 +133,25 @@ def main() -> None:
                     help="failed probes / corrupted outputs a shard may "
                          "accumulate before being declared dead")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build(args: argparse.Namespace):
+    """What the launcher serves, from its arguments: (model config, random
+    weights from ``--seed``, the trace's requests, EngineConfig)."""
     import jax
     from repro.configs import registry
     from repro.data import traces
     from repro.models import transformer
-    from repro.serving import (EngineConfig, FaultInjector, FaultScenario,
-                               LLMEngine)
+    from repro.serving import EngineConfig
 
-    placement = {"vllm": "homogeneous", "lamina": "attention_pool",
-                 None: args.placement}[args.engine]
     cfg = registry.get_smoke_config(args.arch) if args.smoke \
         else registry.get_config(args.arch)
     params = transformer.init_params(jax.random.PRNGKey(args.seed), cfg)
     reqs = traces.generate(args.trace, args.requests, cfg.vocab_size,
                            scale=args.scale, seed=args.seed)
     econf = EngineConfig(
-        placement=placement, partition=args.partition,
+        placement=_placement(args), partition=args.partition,
         attention_workers=args.attention_workers,
         expert_workers=args.expert_workers,
         max_batch=args.max_batch, num_blocks=args.num_blocks,
@@ -140,6 +162,21 @@ def main() -> None:
         prefill_chunk_tokens=args.prefill_chunk_tokens or None,
         fault_retry_limit=args.fault_retry_limit,
         seed=args.seed)
+    return cfg, params, reqs, econf
+
+
+def _placement(args: argparse.Namespace) -> str:
+    return {"vllm": "homogeneous", "lamina": "attention_pool",
+            None: args.placement}[args.engine]
+
+
+def main(argv=None) -> None:
+    args = parse_args(argv)
+    use_compile_cache()
+    from repro.serving import FaultInjector, FaultScenario, LLMEngine
+
+    cfg, params, reqs, econf = build(args)
+    placement = _placement(args)
     injector = None
     if args.fault_scenario:
         injector = FaultInjector(FaultScenario.parse(args.fault_scenario))

@@ -256,7 +256,7 @@ def paged_decode_attention_partial_jnp(q, k_pool, v_pool, block_tables,
     dense head-major view through the table (the copy the Pallas kernel
     avoids) and reuses the dense partial math, so 'jnp' and 'pallas' paged
     backends are bit-comparable. k_scale/v_scale: optional
-    (Hkv, num_blocks, block_size) fp32 scale pools for int8 k_pool/v_pool —
+    (Hkv, num_blocks, 1, block_size) fp32 scale pools for int8 k_pool/v_pool —
     gathered through the same table and folded into the score/PV einsums
     (the dense reference may gather; only the kernels are bound by the
     no-dense-dequant invariant)."""

@@ -579,7 +579,7 @@ def prefill_chunk(params: Params, cfg: ModelConfig, batch: Dict,
     MoE prompts one-shot (same reason prefix sharing recomputes them).
 
     k_scale_pool/v_scale_pool: the int8 pool's fp32 scale sidecars
-    (L, Hkv, num_blocks, bs), threaded per layer next to the value pools
+    (L, Hkv, num_blocks, 1, bs), threaded per layer next to the value pools
     (int8 readback makes chunked outputs quantization-, not chunking-,
     dependent; chunked-vs-oneshot bit-stability is a bf16-pool contract)."""
     if cfg.family not in ("dense", "vlm", "moe"):
@@ -817,7 +817,7 @@ def decode_step_paged(params: Params, cfg: ModelConfig, tokens: jax.Array,
     stays the memory pool's job (PagedKVCache.write_tokens).
 
     k_scale_pool/v_scale_pool: the int8 pool's fp32 per-token scale sidecars
-    (L, Hkv, num_blocks, block_size), threaded per layer next to the value
+    (L, Hkv, num_blocks, 1, block_size), threaded per layer next to the value
     pools so dequantization fuses into the attention kernels (no dense
     dequantized slab on this path — the tentpole invariant).
     """

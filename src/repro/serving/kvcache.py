@@ -153,10 +153,12 @@ class PagedKVCache:
         self.v_pool = jnp.zeros_like(self.k_pool)
         # int8: per-token, per-kv-head fp32 scale pools mirroring the value
         # pools' block axis — block-level ops move scale tiles with their
-        # value tiles ("scales follow blocks"). None on the bf16 path.
+        # value tiles ("scales follow blocks"). None on the bf16 path. Each
+        # block's scales are one (1, block_size) row: the kernels read that
+        # row whole, which is the tile shape the TPU compiler accepts.
         if self.kv_dtype == "int8":
             self.k_scale = jnp.zeros((L, self.cfg.num_kv_heads,
-                                      self.num_blocks, self.block_size),
+                                      self.num_blocks, 1, self.block_size),
                                      jnp.float32)
             self.v_scale = jnp.zeros_like(self.k_scale)
         else:
@@ -651,7 +653,7 @@ class PagedKVCache:
             if pad:
                 ks = jnp.pad(ks, [(0, 0), (0, 0), (0, pad)])
                 vs = jnp.pad(vs, [(0, 0), (0, 0), (0, pad)])
-            shp = (ks.shape[0], ks.shape[1], nb, self.block_size)
+            shp = (ks.shape[0], ks.shape[1], nb, 1, self.block_size)
             self.k_scale = self.k_scale.at[:, :, idx].set(ks.reshape(shp))
             self.v_scale = self.v_scale.at[:, :, idx].set(vs.reshape(shp))
 
@@ -693,8 +695,8 @@ class PagedKVCache:
         if self.kv_dtype == "int8":
             k, ks = kv_quant.quantize_token(k)
             v, vs = kv_quant.quantize_token(v)
-            self.k_scale = self.k_scale.at[:, :, blk, off].set(ks)
-            self.v_scale = self.v_scale.at[:, :, blk, off].set(vs)
+            self.k_scale = self.k_scale.at[:, :, blk, 0, off].set(ks)
+            self.v_scale = self.v_scale.at[:, :, blk, 0, off].set(vs)
         self.k_pool = self.k_pool.at[:, :, blk, off].set(k)
         self.v_pool = self.v_pool.at[:, :, blk, off].set(v)
 
@@ -718,8 +720,8 @@ class PagedKVCache:
         if self.kv_dtype == "int8":
             kn, kns = kv_quant.quantize_token(kn)   # scales (L, Hkv, B)
             vn, vns = kv_quant.quantize_token(vn)
-            self.k_scale = self.k_scale.at[:, :, blk, off].set(kns)
-            self.v_scale = self.v_scale.at[:, :, blk, off].set(vns)
+            self.k_scale = self.k_scale.at[:, :, blk, 0, off].set(kns)
+            self.v_scale = self.v_scale.at[:, :, blk, 0, off].set(vns)
         self.k_pool = self.k_pool.at[:, :, blk, off].set(kn)
         self.v_pool = self.v_pool.at[:, :, blk, off].set(vn)
 
@@ -783,9 +785,9 @@ class PagedKVCache:
         k = self.k_pool[:, :, idx]     # (L, Hkv, B, nb, bs, hd)
         v = self.v_pool[:, :, idx]
         if self.kv_dtype == "int8":    # oracle only — dense dequant is fine
-            k = kv_quant.dequantize_kv(k, self.k_scale[:, :, idx],
+            k = kv_quant.dequantize_kv(k, self.k_scale[:, :, idx, 0],
                                        self.cfg.dtype)
-            v = kv_quant.dequantize_kv(v, self.v_scale[:, :, idx],
+            v = kv_quant.dequantize_kv(v, self.v_scale[:, :, idx, 0],
                                        self.cfg.dtype)
         L, Hkv = k.shape[0], k.shape[1]
         B = len(seq_ids)
@@ -972,7 +974,7 @@ class KVHandoffPayload:
     different ``n_shards``).
 
     int8 pools additionally ship ``k_scales`` / ``v_scales``
-    ``(L, Hkv, n_unique, bs)`` fp32 tiles packed in the same block order —
+    ``(L, Hkv, n_unique, 1, bs)`` fp32 tiles packed in the same block order —
     scales follow their blocks across the wire, and the int8 + scale bytes
     together ≈ halve ``nbytes`` vs a bf16 payload of the same blocks."""
     tables: Dict[int, Tuple[int, ...]]

@@ -56,7 +56,7 @@ def sliced_decode_step(cfg: ModelConfig, pool: AttentionWorkerPool,
     (o-proj + FFN) back on the model worker; when ``expert_pool`` is given
     (paper §7) the routed expert FFNs run on the expert workers instead.
 
-    Int8 pools: k_scale_pool/v_scale_pool are the (L, Hkv, num_blocks,
+    Int8 pools: k_scale_pool/v_scale_pool are the (L, Hkv, num_blocks, 1,
     block_size) scale pools; each layer's slice rides to the worker pool
     alongside its value pools and dequant fuses inside the workers'
     attention backends (no dense dequantized slab on this hot path).

@@ -159,7 +159,7 @@ class AttentionWorkerPool:
         slots, reading ~n× the live KV.
 
         Int8 pools (``kv_dtype="int8"``): k_scale/v_scale are the per-layer
-        scale pools (Hkv, num_blocks, block_size) and each worker's slice
+        scale pools (Hkv, num_blocks, 1, block_size) and each worker's slice
         of them follows its pool slice exactly — head partition slices the
         head axis, block partition the block axis, request partition
         replicates (scales-follow-blocks invariant). Dequant stays fused

@@ -3,7 +3,7 @@ mathematical core of attention offloading, the flash-decode kernel, and the
 sequence-parallel sharding."""
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import combine as C
 

@@ -2,7 +2,7 @@
 for swept (n, steps) and the executable rotation demo."""
 import jax
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import registry
 from repro.core import converter, pipeline

@@ -15,6 +15,13 @@ from repro.serving import (EngineConfig, LLMEngine, Request,
 from repro.serving.kvcache import PagedKVCache
 from repro.serving.scheduler import PrefixIndex
 
+# Full and suffix prefill are separately compiled fp32 programs: XLA may
+# fuse and order the same sums differently, which moves logits and K/V of
+# magnitude <= ~4 at the smoke widths by <= 2e-6 (about 16 fp32 ulps). 2e-5
+# leaves a 10x margin; a wrong prefix or a dropped mask moves them by orders
+# of magnitude more.
+PREFILL_ATOL = 2e-5
+
 
 @pytest.fixture(scope="module")
 def setup():
@@ -44,8 +51,9 @@ def _family(cfg, common, tails=(5, 6, 7, 8), new=8, seed=42):
 @pytest.mark.parametrize("arch", ["llama3-8b", "gemma2-27b"])
 def test_prefill_suffix_bit_parity(arch):
     """Suffix queries over gathered prefix context reproduce the full
-    prefill EXACTLY — logits and suffix KV — including gemma2's local
-    windows, attention sinks, and logit softcap."""
+    prefill (the reference) to fp32 rounding — logits and suffix KV —
+    including gemma2's local windows, attention sinks, and logit
+    softcap."""
     cfg = registry.get_smoke_config(arch)
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
@@ -56,12 +64,15 @@ def test_prefill_suffix_bit_parity(arch):
     kp, vp = cache["k"][:, :, :, :P], cache["v"][:, :, :, :P]
     logits_suf, c2 = transformer.prefill_suffix(
         params, cfg, {"tokens": jnp.asarray(toks[:, P:], jnp.int32)}, kp, vp)
-    np.testing.assert_array_equal(np.asarray(logits_full),
-                                  np.asarray(logits_suf))
-    np.testing.assert_array_equal(np.asarray(cache["k"][:, :, :, P:]),
-                                  np.asarray(c2["k"]))
-    np.testing.assert_array_equal(np.asarray(cache["v"][:, :, :, P:]),
-                                  np.asarray(c2["v"]))
+    np.testing.assert_allclose(np.asarray(logits_suf),
+                               np.asarray(logits_full),
+                               rtol=0, atol=PREFILL_ATOL)
+    np.testing.assert_allclose(np.asarray(c2["k"]),
+                               np.asarray(cache["k"][:, :, :, P:]),
+                               rtol=0, atol=PREFILL_ATOL)
+    np.testing.assert_allclose(np.asarray(c2["v"]),
+                               np.asarray(cache["v"][:, :, :, P:]),
+                               rtol=0, atol=PREFILL_ATOL)
     assert int(c2["len"][0]) == S
 
 

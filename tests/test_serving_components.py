@@ -3,7 +3,7 @@ scheduler FCFS + memory safety, request lifecycle."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import registry
 from repro.serving.kvcache import PagedKVCache

@@ -28,10 +28,7 @@ def test_specs_build_for_all_archs_and_shapes():
     from repro.models import transformer
 
     # AbstractMesh: production shape without needing 256 devices
-    try:  # jax >= 0.5 signature
-        mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:  # 0.4.x takes (name, size) pairs
-        mesh = jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     for arch in registry.ASSIGNED:
         cfg = registry.get_config(arch)
         pshape = jax.eval_shape(
@@ -270,10 +267,6 @@ def test_psum_combine_matches_combine_many_incl_empty_shard():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:
-            from jax.experimental.shard_map import shard_map
         from repro.core import combine as C
         from repro.launch.mesh import make_test_mesh
         n = 4
@@ -296,18 +289,19 @@ def test_psum_combine_matches_combine_many_incl_empty_shard():
         def shard_fn(p):
             local = C.Partial(p.a[0], p.s[0], p.m[0])
             return C.finalize(C.psum_combine(local, "pool"))
-        got = shard_map(shard_fn, mesh=mesh,
-                        in_specs=(C.Partial(P("pool"), P("pool"), P("pool")),),
-                        out_specs=P())(stacked)
+        got = jax.shard_map(
+            shard_fn, mesh=mesh,
+            in_specs=(C.Partial(P("pool"), P("pool"), P("pool")),),
+            out_specs=P())(stacked)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5, rtol=1e-5)
         # all-empty merge stays finite (no NaN from the -inf rebase)
         empty = C.partial_attention(q, k, v, mask=jnp.zeros((S,), bool))
         st_e = C.Partial(*[jnp.stack([a]*n) for a in empty])
-        out_e = shard_map(shard_fn, mesh=mesh,
-                          in_specs=(C.Partial(P("pool"), P("pool"),
-                                              P("pool")),),
-                          out_specs=P())(st_e)
+        out_e = jax.shard_map(
+            shard_fn, mesh=mesh,
+            in_specs=(C.Partial(P("pool"), P("pool"), P("pool")),),
+            out_specs=P())(st_e)
         assert np.all(np.isfinite(np.asarray(out_e)))
         print("PSUM_COMBINE_OK")
     """)
