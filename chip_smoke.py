@@ -9,7 +9,9 @@ non-zero before doing anything. With no option it
   1. runs the paged decode and prefill-chunk kernels (bf16 and int8 pools)
      at tinyllama-1.1b serving shapes and compares each with its jnp
      reference, computed in fp32 at the highest matmul precision;
-  2. serves 8 azure-conv requests with tinyllama-1.1b at its published
+  2. makes the served KV pool, bf16 and int8, and checks that the int8
+     pool holds no more of the device than its element bytes say;
+  3. serves 8 azure-conv requests with tinyllama-1.1b at its published
      widths and depth (random weights from a seed) through ``LLMEngine``,
      built exactly as ``repro.launch.serve`` builds it, with the
      attention_pool placement and the Pallas backend, and checks that every
@@ -50,30 +52,43 @@ def log(msg: str) -> None:
 # ---------------------------------------------------------------------------
 class CompileLog:
     """Counts backend compiles (persistent-cache loads included) and their
-    seconds, plus the persistent cache's hits and misses."""
+    seconds, plus the persistent cache's hits and misses, per phase. JAX
+    counts a miss only where it writes the program to the cache (a compile
+    of at least ``jax_persistent_cache_min_compile_time_secs``); the names
+    of those programs are kept."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
 
     def __init__(self):
         import jax
 
-        self.programs, self.seconds, self.hits, self.misses = 0, 0.0, 0, 0
+        self.programs, self.seconds, self.hits, self.missed = 0, 0.0, 0, []
+        pending = []   # a miss is recorded inside the compile it belongs to
 
-        def on_duration(event, secs, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
+        def on_duration(event, secs, fun_name="?", **_):
+            if event == self.COMPILE:
                 self.programs += 1
                 self.seconds += secs
+                if pending:
+                    self.missed.append(fun_name)
+                    pending.clear()
 
         def on_event(event, **_):
             if event == "/jax/compilation_cache/cache_hits":
                 self.hits += 1
             elif event == "/jax/compilation_cache/cache_misses":
-                self.misses += 1
+                pending.append(event)
 
         jax.monitoring.register_event_duration_secs_listener(on_duration)
         jax.monitoring.register_event_listener(on_event)
 
-    def __str__(self):
-        return (f"programs={self.programs} compile_s={self.seconds:.1f} "
-                f"cache_hits={self.hits} cache_misses={self.misses}")
+    def phase(self) -> str:
+        """What was counted since the last call, then start counting anew."""
+        text = (f"programs={self.programs} compile_s={self.seconds:.1f} "
+                f"cache_hits={self.hits} cache_misses={len(self.missed)} "
+                f"missed={sorted(set(self.missed))}")
+        self.programs, self.seconds, self.hits, self.missed = 0, 0.0, 0, []
+        return text
 
 
 # ---------------------------------------------------------------------------
@@ -201,7 +216,43 @@ def kernel_phase(*, interpret: bool = False) -> None:
 
 
 # ---------------------------------------------------------------------------
-# phase 2: the served run
+# phase 2: the served KV pool's bytes on the device
+# ---------------------------------------------------------------------------
+def pool_phase(num_blocks: int = 4096) -> None:
+    """The served KV pool (tinyllama-1.1b, ``num_blocks`` x 16), bf16 and
+    int8: the bytes the device holds for each pool array against its
+    element bytes. The int8 pool, scales included, must hold at most
+    (hd + 4) / (2 hd) of the bf16 pool's device bytes, as
+    ``pool_bytes_resident`` counts; a scale layout padded on the device
+    breaks that."""
+    from repro.configs import registry
+    from repro.serving.kvcache import PagedKVCache
+
+    cfg = registry.get_config("tinyllama-1.1b")
+    held = {}
+    for kv_dtype in ("bf16", "int8"):
+        kv = PagedKVCache(cfg, num_blocks, BS, kv_dtype=kv_dtype)
+        arrays = {"k_pool": kv.k_pool, "v_pool": kv.v_pool}
+        if kv.k_scale is not None:
+            arrays.update(k_scale=kv.k_scale, v_scale=kv.v_scale)
+        for name, x in arrays.items():
+            lay = x.format.layout
+            log(f"pool {kv_dtype} {name} {x.dtype}{list(x.shape)}: "
+                f"device_bytes={x.on_device_size_in_bytes()} "
+                f"element_bytes={x.nbytes} "
+                f"layout={lay.major_to_minor}:{lay.tiling}")
+        held[kv_dtype] = sum(x.on_device_size_in_bytes()
+                             for x in arrays.values())
+        log(f"pool {kv_dtype}: device_bytes={held[kv_dtype]} "
+            f"pool_bytes_resident={kv.pool_bytes_resident}")
+        del kv, arrays
+    ratio, bound = held["int8"] / held["bf16"], (HD + 4) / (2 * HD)
+    log(f"pool int8/bf16 device bytes: {ratio:.4f} (analytic {bound:.4f})")
+    assert ratio <= bound, f"int8 pool holds {ratio:.4f} of bf16 > {bound}"
+
+
+# ---------------------------------------------------------------------------
+# phase 3: the served run
 # ---------------------------------------------------------------------------
 SERVE_ARGV = [
     "--arch", "tinyllama-1.1b", "--placement", "attention_pool",
@@ -373,15 +424,14 @@ def main(argv=None) -> int:
         f"device_kind={dev.device_kind} device_count={len(devices)} "
         f"compile_cache={cache}")
     t0 = time.time()
-    if args.chips == 4:
-        split_phase(4)
-        log(f"phase split: passed in {time.time() - t0:.1f}s; {compiles}")
-    else:
-        kernel_phase()
-        log(f"phase kernels: passed in {time.time() - t0:.1f}s; {compiles}")
+    phases = ([("split", split_phase)] if args.chips == 4 else
+              [("kernels", kernel_phase), ("pool", pool_phase),
+               ("served", served_run)])
+    for name, run in phases:
         t1 = time.time()
-        served_run()
-        log(f"phase served: passed in {time.time() - t1:.1f}s; {compiles}")
+        run()
+        log(f"phase {name}: passed in {time.time() - t1:.1f}s; "
+            f"{compiles.phase()}")
     stats = dev.memory_stats() or {}
     log(f"peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
         f"total_s={time.time() - t0:.1f}")

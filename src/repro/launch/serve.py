@@ -151,7 +151,9 @@ def build(args: argparse.Namespace):
     reqs = traces.generate(args.trace, args.requests, cfg.vocab_size,
                            scale=args.scale, seed=args.seed)
     econf = EngineConfig(
-        placement=_placement(args), partition=args.partition,
+        placement={"vllm": "homogeneous", "lamina": "attention_pool",
+                   None: args.placement}[args.engine],
+        partition=args.partition,
         attention_workers=args.attention_workers,
         expert_workers=args.expert_workers,
         max_batch=args.max_batch, num_blocks=args.num_blocks,
@@ -165,18 +167,12 @@ def build(args: argparse.Namespace):
     return cfg, params, reqs, econf
 
 
-def _placement(args: argparse.Namespace) -> str:
-    return {"vllm": "homogeneous", "lamina": "attention_pool",
-            None: args.placement}[args.engine]
-
-
 def main(argv=None) -> None:
     args = parse_args(argv)
     use_compile_cache()
     from repro.serving import FaultInjector, FaultScenario, LLMEngine
 
     cfg, params, reqs, econf = build(args)
-    placement = _placement(args)
     injector = None
     if args.fault_scenario:
         injector = FaultInjector(FaultScenario.parse(args.fault_scenario))
@@ -202,7 +198,7 @@ def main(argv=None) -> None:
         print(f"\ninterrupted — cancelled {n} in-flight request(s), "
               f"partial outputs kept; draining stats")
     s = eng.stats.summary()
-    print(f"placement={placement} partition={args.partition} "
+    print(f"placement={econf.placement} partition={args.partition} "
           f"scheduler={args.scheduler} trace={args.trace} "
           f"requests={len(reqs)} tokens={s['tokens_generated']} "
           f"mean_batch={s['mean_batch']:.2f} "

@@ -69,3 +69,39 @@ def test_kernel_check_catches_wrong_prefix_block(smoke, monkeypatch):
             q, kp, vp, (bt + 1) % kp.shape[1], kc, vc, **kw))
     with pytest.raises(AssertionError, match="max_err"):
         smoke.check_chunk(False, interpret=True)
+
+
+def test_pool_phase_int8_share_of_bf16(smoke):
+    # on the CPU a pool array holds exactly its element bytes
+    smoke.pool_phase(num_blocks=64)
+
+
+def test_compile_log_names_cache_writes(smoke, tmp_path):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def written_to_the_cache(x):
+        return x * 3 + 1
+
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    x = jnp.arange(7.0)
+    log = smoke.CompileLog()
+    try:
+        # every compile, however small and quick, goes to the cache
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        jax.jit(written_to_the_cache)(x).block_until_ready()
+        counted = log.phase()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert "cache_misses=1" in counted, counted
+    assert "missed=['jit(written_to_the_cache)']" in counted, counted
+    assert log.phase().startswith("programs=0 "), "phase() starts anew"

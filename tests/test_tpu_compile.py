@@ -12,6 +12,8 @@ The topology is described inside a fixture: only one process may load the
 TPU compiler's library at a time, so nothing here may touch it while the
 module is imported.
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,18 +37,25 @@ B, NB, POOL, BS, C = 32, 64, 4096, 16, 64
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
 
+    # the TPU compiler's library otherwise writes its logs to a fixed
+    # directory outside the checkout
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without one; keep these compiles out of it
+    # cannot be read back without one; keep these compiles out of it (the
+    # reset drops a cache this process has already opened)
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
     yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
 
 
 def _compiled_text(fn, *args) -> str:
