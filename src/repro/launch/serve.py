@@ -204,6 +204,7 @@ def main(argv=None) -> None:
           f"mean_batch={s['mean_batch']:.2f} "
           f"throughput={s['throughput_tok_s']:.1f} tok/s "
           f"mean_tbt={s['mean_tbt_s']*1000:.1f} ms "
+          f"compile_steps={s['compile_steps']} "
           f"preemptions={s['preemptions']}")
     if args.prefill_chunk_tokens:
         print(f"chunked_prefill chunk_tokens={args.prefill_chunk_tokens} "

@@ -321,7 +321,7 @@ class DecodeEngine(LLMEngine):
                 self.transfer_q.remove(h)
                 self.waiting_q.push(h)
                 self.stats.handoff_latencies.append(
-                    time.time() - h.enqueue_s)
+                    time.perf_counter() - h.enqueue_s)
                 self._emit("transfer_done", h.rid,
                            blocks=h.payload.n_blocks,
                            steps=self._step_no - h.enqueued_step,

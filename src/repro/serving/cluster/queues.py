@@ -53,7 +53,7 @@ class Handoff:
     payload: KVHandoffPayload
     replica: int
     enqueued_step: int                  # decode engine step at arrival
-    enqueue_s: float = dataclasses.field(default_factory=time.time)
+    enqueue_s: float = dataclasses.field(default_factory=time.perf_counter)
     # set by prealloc (src→dst block mapping); reset on transfer abort
     mapping: Optional[Dict[int, int]] = None
     cursor: int = 0                     # payload blocks written so far

@@ -69,6 +69,29 @@ step is deterministic and nothing was committed before validation. NaN/inf
 that is NOT attributable to an injected fault raises
 :class:`CorruptedLogitsError` naming the requests and step — garbage is
 never silently sampled.
+
+Tracing: :meth:`LLMEngine.step` writes ``jax.profiler.TraceAnnotation``
+spans into the profiler's own trace, so they share the device trace's
+clock and cost only their enter and exit while no profiler runs. The
+names are fixed, carry no arguments, and are read back exactly by the
+benchmark (``bench/spans.py``), which charges each stretch of device idle
+time to the innermost ``engine.*`` span open over it:
+
+  * ``engine.step`` — the whole of ``step()``;
+  * ``engine.sched`` — fault bookkeeping, the pre-admission hook,
+    admission and the stall check, the choice of the decode batch and
+    pool-pressure preemption, retirement;
+  * ``engine.pool`` — block tables, prefix gather indices, the
+    placement's per-worker table walk, token appends, every pool write,
+    and the KV byte accounting;
+  * ``engine.model`` — each jitted model call (decode step, prefill chunk,
+    one-shot and suffix prefill) with its host-to-device inputs and, for
+    decode, the wait for its result and its finiteness check;
+  * ``engine.sample`` — ``_sample`` and the transfer of the sampled tokens
+    into ``Request.record_token``.
+
+Spans nest (a one-shot prefill inside admission is ``engine.model``
+inside ``engine.sched``) and close when the step raises.
 """
 from __future__ import annotations
 
@@ -79,6 +102,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import transformer
 from repro.models.common import ModelConfig
@@ -216,9 +240,11 @@ class LLMEngine:
         self.stats = EngineStats()
         self.stats.kv_pool_bytes_resident = self.kv.pool_bytes_resident
         self._decode_jit = jax.jit(self.placement.decode_fn())
-        self._prefill_jit = jax.jit(
-            lambda p, b: transformer.prefill(p, cfg, b,
-                                             max_seq=b["tokens"].shape[1]))
+
+        def prefill(p, b):
+            return transformer.prefill(p, cfg, b,
+                                       max_seq=b["tokens"].shape[1])
+        self._prefill_jit = jax.jit(prefill)
         def _suffix_prefill(p, b, k_pool, v_pool, idx,
                             k_scale=None, v_scale=None):
             # fused prefix gather: the shared blocks' KV is sliced out of
@@ -249,11 +275,17 @@ class LLMEngine:
         # so a second long prompt reuses the first one's compiled programs
         # (one-shot prefill, by contrast, compiles per distinct prompt
         # length); only the final partial chunk adds a per-length shape.
-        self._prefill_chunk_jit = jax.jit(
-            lambda p, b, kp, vp, idx, ks=None, vs=None:
-                transformer.prefill_chunk(
-                    p, cfg, b, kp, vp, idx, backend=econf.decode_backend,
-                    k_scale_pool=ks, v_scale_pool=vs))
+        def prefill_chunk(p, b, kp, vp, idx, ks=None, vs=None):
+            return transformer.prefill_chunk(
+                p, cfg, b, kp, vp, idx, backend=econf.decode_backend,
+                k_scale_pool=ks, v_scale_pool=vs)
+        self._prefill_chunk_jit = jax.jit(prefill_chunk)
+        # the programs whose new executables make a step a compile step,
+        # held apart from the attributes so that a wrapper put in their
+        # place still counts the program it wraps
+        self._programs = (self._decode_jit, self._prefill_jit,
+                          self._prefill_suffix_jit, self._prefill_chunk_jit)
+        self._tokens_out = 0        # tokens recorded by this engine
         # Prefill COMPUTE can only be skipped when suffix-only prefill is
         # bit-identical to the full one. MoE capacity dispatch couples the
         # tokens of a routing group (expert capacity and reduction shapes
@@ -262,7 +294,7 @@ class LLMEngine:
         self._skip_prefill_compute = cfg.family != "moe"
         # fault tolerance: per-shard health machine (always live) plus the
         # optional injector; _recovering maps a shard-death victim's rid to
-        # the wall-clock instant its shard was declared dead, closed out
+        # the perf_counter instant its shard was declared dead, closed out
         # (into stats.recovery_latencies) when the request is decodable
         # again on the surviving shards
         self._fault = fault_injector
@@ -284,10 +316,11 @@ class LLMEngine:
         batch = [reqs] if single else list(reqs)
         handles = []
         for req in batch:
+            req.arrival_s = time.perf_counter()
             self._emit("submit", req.rid)
             if not req.output and req.done():      # max_new_tokens == 0
                 req.state = State.FINISHED
-                req.finish_s = time.time()
+                req.finish_s = req.arrival_s
                 self._emit("finish", req.rid, tokens=0)
             else:
                 self.sched.submit([req])
@@ -332,10 +365,37 @@ class LLMEngine:
         heartbeat probes, straggler observation) runs first, so a shard
         death detected at the step boundary is recovered before this very
         step's admission wave — the evicted victims re-admit immediately
-        onto the surviving shards."""
-        self._step_no += 1
-        self._fault_tick()
-        self._pre_admit_tick()
+        onto the surviving shards.
+
+        The whole step is timed into ``stats.step_times``, unless one of
+        the engine's jitted programs built a new executable during it: it
+        then counts in ``stats.compile_steps`` instead."""
+        t0 = time.perf_counter()
+        programs, tokens = self._programs_built(), self._tokens_out
+        with TraceAnnotation("engine.step"):
+            self._step_no += 1
+            with TraceAnnotation("engine.sched"):
+                self._fault_tick()
+                self._pre_admit_tick()
+                self._admission_wave()
+                self._check_stall()
+            self._prefill_chunk_iteration()
+            with TraceAnnotation("engine.sched"):
+                self._note_recoveries()
+            self._decode_iteration()
+            with TraceAnnotation("engine.sched"):
+                self._retire()
+        if self._programs_built() > programs:
+            self.stats.compile_steps += 1
+        else:
+            self.stats.step_times.append(time.perf_counter() - t0)
+            self.stats.timed_tokens += self._tokens_out - tokens
+
+    def _programs_built(self) -> int:
+        """Executables the engine's jitted programs hold so far."""
+        return sum(f._cache_size() for f in self._programs)
+
+    def _admission_wave(self) -> None:
         while True:
             admitted = self.sched.admit()
             for req in admitted:
@@ -361,6 +421,8 @@ class LLMEngine:
             # returned its blocks — the next waiting request may fit NOW
             if self.sched.running or not admitted:
                 break
+
+    def _check_stall(self) -> None:
         if not self.sched.running and self.sched.waiting:
             head = self.sched.waiting[0]
             need = self.sched.stored_tokens(head) + self.sched.decode_headroom
@@ -380,10 +442,6 @@ class LLMEngine:
                     f"({self.kv.num_free} free) and nothing is running — "
                     f"it can never be admitted; shrink the prompt or grow "
                     f"num_blocks" + self.kv._degraded_note())
-        self._prefill_chunk_iteration()
-        self._note_recoveries()
-        self._decode_iteration()
-        self._retire()
 
     def run(self, max_steps: int = 10_000) -> EngineStats:
         steps = 0
@@ -427,7 +485,7 @@ class LLMEngine:
         every already-yielded token stays final. Returns the number of
         requests cancelled."""
         cancelled = self.sched.cancel_all()
-        now = time.time()
+        now = time.perf_counter()
         for req in cancelled:
             req.state = State.FINISHED
             req.finish_s = now
@@ -507,7 +565,7 @@ class LLMEngine:
         policy, so recovery works under ``fcfs`` too — and MID-PREFILL
         victims are allowed here (their prefill cursor resets with the
         eviction), the one place that invariant yields."""
-        t0 = time.time()
+        t0 = time.perf_counter()
         victims = set(self.kv.seqs_on_shard(shard))
         # quarantine BEFORE freeing: the dead shard's blocks must not be
         # handed back out to the re-admission wave
@@ -535,7 +593,7 @@ class LLMEngine:
         for r in self.sched.running:
             t0 = self._recovering.get(r.rid)
             if t0 is not None and self.sched.prefill_done(r.rid):
-                lat = time.time() - t0
+                lat = time.perf_counter() - t0
                 del self._recovering[r.rid]
                 self.stats.recovery_latencies.append(lat)
                 self.stats.requests_recovered += 1
@@ -573,8 +631,7 @@ class LLMEngine:
 
     def _prefill(self, req: Request) -> None:
         logits = self._prefill_known(req.rid, req.prompt)
-        tok = self._sample([req], logits)
-        req.record_token(int(tok[0]))
+        self._sample_and_record([req], logits)
         # the sampled token's K/V gets stored by the next decode pass (it is
         # that step's input token); kv.lengths stays = stored tokens
 
@@ -611,27 +668,32 @@ class LLMEngine:
             # memoised gather indices: a prefix-sharing admission wave's K
             # recipients all resolve to the donor's physical blocks, so the
             # whole wave reuses one converted index array
-            idx = self.kv.gather_prefix_indices(rid, shared)
-            toks = jnp.asarray([list(known[shared:])], jnp.int32)
-            logits, cache = self._prefill_suffix_jit(
-                self.params, {"tokens": toks}, self.kv.k_pool,
-                self.kv.v_pool, idx, **self._scale_kwargs("k_scale",
-                                                          "v_scale"))
+            with TraceAnnotation("engine.pool"):
+                idx = self.kv.gather_prefix_indices(rid, shared)
+            with TraceAnnotation("engine.model"):
+                toks = jnp.asarray([list(known[shared:])], jnp.int32)
+                logits, cache = self._prefill_suffix_jit(
+                    self.params, {"tokens": toks}, self.kv.k_pool,
+                    self.kv.v_pool, idx, **self._scale_kwargs("k_scale",
+                                                              "v_scale"))
             # suffix cache k/v are head-major (L, 1, Hkv, S-shared, hd)
-            self.kv.write_prefill(rid, cache["k"][:, 0], cache["v"][:, 0],
-                                  start_token=shared)
+            with TraceAnnotation("engine.pool"):
+                self.kv.write_prefill(rid, cache["k"][:, 0],
+                                      cache["v"][:, 0], start_token=shared)
             self.stats.prefill_tokens_skipped += shared
             self.stats.max_prefill_slab_tokens = max(
                 self.stats.max_prefill_slab_tokens, len(known) - shared)
             return logits
-        toks = jnp.asarray([list(known)], jnp.int32)
         self.stats.max_prefill_slab_tokens = max(
             self.stats.max_prefill_slab_tokens, len(known))
-        logits, cache = self._prefill_jit(self.params, {"tokens": toks})
+        with TraceAnnotation("engine.model"):
+            toks = jnp.asarray([list(known)], jnp.int32)
+            logits, cache = self._prefill_jit(self.params, {"tokens": toks})
         # cache k/v are head-major (L, 1, Hkv, S, hd) — the pool's layout
-        self.kv.write_prefill(rid, cache["k"][:, 0, :, shared:],
-                              cache["v"][:, 0, :, shared:],
-                              start_token=shared)
+        with TraceAnnotation("engine.pool"):
+            self.kv.write_prefill(rid, cache["k"][:, 0, :, shared:],
+                                  cache["v"][:, 0, :, shared:],
+                                  start_token=shared)
         return logits
 
     # ------------------------------------------------------------------
@@ -645,61 +707,69 @@ class LLMEngine:
         pool blocks (plus the in-chunk causal mask), its KV is written as
         it completes (blocks allocated incrementally), and only the FINAL
         chunk samples the request's first token."""
-        req = self.sched.next_prefill()
-        if req is None:
-            return
-        rid = req.rid
-        # re-admission after preemption recomputes prompt + generated
-        # tokens minus the still-unstored last one (the §5 recovery path)
-        known = list(req.prompt) + req.output[:-1] if req.output \
-            else req.prompt
-        total = len(known)
-        cursor = self.sched.prefill_cursor(rid)
-        target = min(cursor + self._chunk_tokens, total)
-        grow = self.kv.blocks_needed(target) - len(self.kv.tables[rid])
-        # the FINAL chunk re-establishes the decode headroom one-shot
-        # admission reserves up front: completing a prefill with zero slack
-        # would strand the request at its first decode-growth block
-        headroom = 0
-        if target >= total:
-            headroom = (self.kv.blocks_needed(total +
-                                              self.sched.decode_headroom) -
-                        self.kv.blocks_needed(total))
-        if grow + headroom > 0:
-            # the chunk may not starve the decode batch either: reserve the
-            # blocks this iteration's decodes are about to append before
-            # taking any for the chunk (the decoders are what retires and
-            # frees the rest of this prompt's allocation)
-            reserve = sum(self.kv.blocks_to_append(r.rid)
-                          for r in self.sched.running
-                          if r.state == State.RUNNING
-                          and self.sched.prefill_done(r.rid))
-            if not self._free_blocks_for_chunk(req,
-                                               grow + headroom + reserve):
-                return  # stall this iteration: admission charged only the
-                # first chunk, so the rest of the allocation arrives as
-                # running requests retire — decode continues meanwhile
-        toks = jnp.asarray([list(known[cursor:target])], jnp.int32)
-        idx = self.kv.gather_prefix_indices(rid, cursor)
-        logits, cache = self._prefill_chunk_jit(
-            self.params, {"tokens": toks}, self.kv.k_pool, self.kv.v_pool,
-            idx, **self._scale_kwargs("ks", "vs"))
-        # chunk cache k/v are head-major (L, 1, Hkv, C, hd) — the pool's
-        # layout; write_prefill_chunk extends the allocation then scatters
-        self.kv.write_prefill_chunk(rid, cache["k"][:, 0], cache["v"][:, 0],
-                                    start_token=cursor)
-        self.stats.prefill_chunks_run += 1
-        self.stats.max_prefill_slab_tokens = max(
-            self.stats.max_prefill_slab_tokens, target - cursor)
-        self.placement.log_prefill_chunk(target - cursor)
-        self._emit("chunk", rid, start=cursor, tokens=target - cursor,
-                   remaining=total - target)
-        self.sched.advance_prefill(req, target)
+        with TraceAnnotation("engine.sched"):
+            req = self.sched.next_prefill()
+            if req is None:
+                return
+            rid = req.rid
+            # re-admission after preemption recomputes prompt + generated
+            # tokens minus the still-unstored last one (the §5 recovery
+            # path)
+            known = list(req.prompt) + req.output[:-1] if req.output \
+                else req.prompt
+            total = len(known)
+            cursor = self.sched.prefill_cursor(rid)
+            target = min(cursor + self._chunk_tokens, total)
+            grow = self.kv.blocks_needed(target) - len(self.kv.tables[rid])
+            # the FINAL chunk re-establishes the decode headroom one-shot
+            # admission reserves up front: completing a prefill with zero
+            # slack would strand the request at its first decode-growth
+            # block
+            headroom = 0
+            if target >= total:
+                headroom = (self.kv.blocks_needed(total +
+                                                  self.sched.decode_headroom)
+                            - self.kv.blocks_needed(total))
+            if grow + headroom > 0:
+                # the chunk may not starve the decode batch either: reserve
+                # the blocks this iteration's decodes are about to append
+                # before taking any for the chunk (the decoders are what
+                # retires and frees the rest of this prompt's allocation)
+                reserve = sum(self.kv.blocks_to_append(r.rid)
+                              for r in self.sched.running
+                              if r.state == State.RUNNING
+                              and self.sched.prefill_done(r.rid))
+                if not self._free_blocks_for_chunk(
+                        req, grow + headroom + reserve):
+                    return  # stall this iteration: admission charged only
+                    # the first chunk, so the rest of the allocation
+                    # arrives as running requests retire — decode
+                    # continues meanwhile
+        with TraceAnnotation("engine.pool"):
+            idx = self.kv.gather_prefix_indices(rid, cursor)
+        with TraceAnnotation("engine.model"):
+            toks = jnp.asarray([list(known[cursor:target])], jnp.int32)
+            logits, cache = self._prefill_chunk_jit(
+                self.params, {"tokens": toks}, self.kv.k_pool,
+                self.kv.v_pool, idx, **self._scale_kwargs("ks", "vs"))
+        with TraceAnnotation("engine.pool"):
+            # chunk cache k/v are head-major (L, 1, Hkv, C, hd) — the
+            # pool's layout; write_prefill_chunk extends the allocation
+            # then scatters
+            self.kv.write_prefill_chunk(rid, cache["k"][:, 0],
+                                        cache["v"][:, 0], start_token=cursor)
+            self.stats.prefill_chunks_run += 1
+            self.stats.max_prefill_slab_tokens = max(
+                self.stats.max_prefill_slab_tokens, target - cursor)
+            self.placement.log_prefill_chunk(target - cursor)
+        with TraceAnnotation("engine.sched"):
+            self._emit("chunk", rid, start=cursor, tokens=target - cursor,
+                       remaining=total - target)
+            self.sched.advance_prefill(req, target)
         if target >= total and not req.output:
             # last chunk's last position seeds sampling — same contract as
             # the one-shot prefill (TTFT lands here)
-            tok = self._sample([req], logits)
-            req.record_token(int(tok[0]))
+            self._sample_and_record([req], logits)
 
     def _free_blocks_for_chunk(self, req: Request, need: int) -> bool:
         """Check `need` blocks are free before a chunk allocation. Chunk
@@ -740,21 +810,28 @@ class LLMEngine:
     # decode
     # ------------------------------------------------------------------
     def _decode_iteration(self) -> None:
-        running = [r for r in self.sched.running
-                   if r.state == State.RUNNING
-                   and self.sched.prefill_done(r.rid)]
-        if not running:
-            return
-        running = self._resolve_pool_pressure(running)
+        with TraceAnnotation("engine.sched"):
+            running = [r for r in self.sched.running
+                       if r.state == State.RUNNING
+                       and self.sched.prefill_done(r.rid)]
+            if running:
+                running = self._resolve_pool_pressure(running)
         if not running:
             return
         ids = [r.rid for r in running]
-        # placement-specific per-iteration operands + per-worker accounting
-        extra = self.placement.decode_extra_args(self.kv, ids)
-        tables, lens = self.kv.block_table_batch(ids)
-        tokens = jnp.asarray([r.output[-1] for r in running], jnp.int32)
-        t0 = time.time()
-        out = self._decode_validated(running, tokens, tables, lens, extra)
+        with TraceAnnotation("engine.pool"):
+            # one walk of the batch's tables counts the live KV tokens the
+            # step reads, for the placement's per-worker accounting and
+            # for stats.kv_bytes_read alike
+            live = self.kv.unique_live_tokens(ids)
+            # placement-specific per-iteration operands + per-worker
+            # accounting
+            extra = self.placement.decode_extra_args(self.kv, ids, live)
+            tables, lens = self.kv.block_table_batch(ids)
+        with TraceAnnotation("engine.model"):
+            tokens = jnp.asarray([r.output[-1] for r in running], jnp.int32)
+            out = self._decode_validated(running, tokens, tables, lens,
+                                         extra)
         if out is None:
             # a shard died mid-decode: this iteration is aborted with
             # NOTHING committed (no append, no pool write, no sample) —
@@ -762,25 +839,22 @@ class LLMEngine:
             # outputs unchanged, so greedy bit-parity holds
             return
         logits, updates = out
-        dt = time.time() - t0
-        # placement is the memory pool's job: append the input token's K/V
-        # (allocator bookkeeping per sequence, then ONE batched scatter)
-        positions = [int(n) for n in lens]
-        for r in running:
-            self.kv.append_token(r.rid)
-        self.kv.write_tokens(ids, updates["k_new"], updates["v_new"],
-                             positions)
-        toks = self._sample(running, logits)
-        for i, r in enumerate(running):
-            r.record_token(int(toks[i]))
-        self.placement.log_step(len(running))
-        self.stats.steps += 1
-        self.stats.kv_pool_bytes_resident = self.kv.pool_bytes_resident
-        self.stats.kv_bytes_read += (self.kv.unique_live_tokens(ids) *
-                                     self.kv.bytes_per_live_token())
-        self.stats.tokens_generated += len(running)
-        self.stats.batch_sizes.append(len(running))
-        self.stats.step_times.append(dt)
+        with TraceAnnotation("engine.pool"):
+            # placement is the memory pool's job: append the input token's
+            # K/V (allocator bookkeeping per sequence, then ONE batched
+            # scatter)
+            positions = [int(n) for n in lens]
+            for r in running:
+                self.kv.append_token(r.rid)
+            self.kv.write_tokens(ids, updates["k_new"], updates["v_new"],
+                                 positions)
+            self.placement.log_step(len(running))
+            self.stats.steps += 1
+            self.stats.kv_pool_bytes_resident = self.kv.pool_bytes_resident
+            self.stats.kv_bytes_read += live * self.kv.bytes_per_live_token()
+            self.stats.tokens_generated += len(running)
+            self.stats.batch_sizes.append(len(running))
+        self._sample_and_record(running, logits)
 
     def _decode_validated(self, running: List[Request], tokens, tables,
                           lens, extra):
@@ -877,6 +951,14 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # sampling (per-request PRNG streams — SamplingParams.seed honoured)
     # ------------------------------------------------------------------
+    def _sample_and_record(self, reqs: List[Request],
+                           logits: jax.Array) -> None:
+        with TraceAnnotation("engine.sample"):
+            toks = self._sample(reqs, logits)
+            for i, r in enumerate(reqs):
+                r.record_token(int(toks[i]))
+        self._tokens_out += len(reqs)
+
     def _sample(self, reqs: List[Request], logits: jax.Array) -> jax.Array:
         self._guard_finite(reqs, logits)
         keys = jnp.stack([self._request_key(r) for r in reqs])

@@ -123,8 +123,10 @@ class PlacementStrategy:
         raise NotImplementedError
 
     # ---- per-iteration host-side operands + data-dependent accounting ----
-    def decode_extra_args(self, kv: PagedKVCache,
-                          ids: Sequence[int]) -> Tuple:
+    def decode_extra_args(self, kv: PagedKVCache, ids: Sequence[int],
+                          live_tokens: int) -> Tuple:
+        """``live_tokens`` is ``kv.unique_live_tokens(ids)``, counted once
+        per step by the engine."""
         return ()
 
     # ---- analytic per-iteration transfer accounting ----
@@ -197,8 +199,8 @@ class AttentionPoolPlacement(PlacementStrategy):
                 k_scale_pool=k_scale_pool, v_scale_pool=v_scale_pool)
         return step
 
-    def decode_extra_args(self, kv: PagedKVCache,
-                          ids: Sequence[int]) -> Tuple:
+    def decode_extra_args(self, kv: PagedKVCache, ids: Sequence[int],
+                          live_tokens: int) -> Tuple:
         """Per-worker live-token KV-read accounting (data-dependent, so
         host-side — the jitted step's python body fires at trace time only)
         plus, for the block partition, the compacted per-shard local tables
@@ -215,8 +217,7 @@ class AttentionPoolPlacement(PlacementStrategy):
         # sharer): unique_live_tokens dedupes; without sharing it equals
         # the plain per-sequence length sum
         if pool.partition == "head":
-            total = kv.unique_live_tokens(ids)
-            pool.log_paged_kv([total] * pool.n, L,
+            pool.log_paged_kv([live_tokens] * pool.n, L,
                               kv_head_fraction=1.0 / pool.n)
         else:  # request: each worker walks only its requests' tables
             toks = [kv.unique_live_tokens([ids[i] for i in idx])

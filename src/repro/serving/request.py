@@ -42,7 +42,8 @@ class Request:
     rid: int = dataclasses.field(default_factory=lambda: next(_ids))
     state: State = State.WAITING
     output: List[int] = dataclasses.field(default_factory=list)
-    arrival_s: float = dataclasses.field(default_factory=time.time)
+    # stamps are time.perf_counter(); arrival_s is set by LLMEngine.submit
+    arrival_s: Optional[float] = None
     first_token_s: Optional[float] = None
     finish_s: Optional[float] = None
     token_times: List[float] = dataclasses.field(default_factory=list)
@@ -59,7 +60,7 @@ class Request:
         return len(self.output) >= p.max_new_tokens
 
     def record_token(self, tok: int) -> None:
-        now = time.time()
+        now = time.perf_counter()
         if self.first_token_s is None:
             self.first_token_s = now
         self.output.append(int(tok))

@@ -27,7 +27,13 @@ class EngineStats:
     steps: int = 0
     tokens_generated: int = 0
     batch_sizes: List[int] = dataclasses.field(default_factory=list)
+    # seconds of every whole engine step (time.perf_counter) except the
+    # compile steps: those in which one of the engine's jitted programs
+    # built a new executable, counted apart; timed_tokens are the output
+    # tokens recorded in the timed steps
     step_times: List[float] = dataclasses.field(default_factory=list)
+    timed_tokens: int = 0
+    compile_steps: int = 0
     # per-request latency samples (seconds) — populated by observe_request
     # on retirement; the percentile surface bench_serving reports
     request_ttfts: List[float] = dataclasses.field(default_factory=list)
@@ -81,11 +87,13 @@ class EngineStats:
 
     @property
     def throughput(self) -> float:
+        """Output tokens per second of step time, compile steps left out."""
         t = sum(self.step_times)
-        return self.tokens_generated / t if t > 0 else 0.0
+        return self.timed_tokens / t if t > 0 else 0.0
 
     @property
     def mean_tbt(self) -> float:
+        """Mean seconds of an engine step, compile steps left out."""
         return float(np.mean(self.step_times)) if self.step_times else 0.0
 
     @property
@@ -143,6 +151,7 @@ class EngineStats:
             "mean_batch": self.mean_batch,
             "throughput_tok_s": self.throughput,
             "mean_tbt_s": self.mean_tbt,
+            "compile_steps": self.compile_steps,
             "preemptions": self.preemptions,
             "blocks_shared": self.blocks_shared,
             "prefill_tokens_skipped": self.prefill_tokens_skipped,

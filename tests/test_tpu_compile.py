@@ -6,7 +6,9 @@ cannot lay out. These tests compile the four served kernel variants (paged
 decode and prefill-chunk, bf16 and int8 pools) at tinyllama-1.1b and
 llama3-8b widths for a described ``v5e:2x2`` topology, with no chip
 attached, and the cross-chip head and block splits over its four devices.
-Each asserts that the compiled program holds the Mosaic kernel.
+Each asserts that the compiled program holds the Mosaic kernel, under the
+instruction name that the device trace shows and the benchmark's roofline
+readers match (``paged_decode`` for decode, ``paged_prefill`` for chunks).
 
 The topology is described inside a fixture: only one process may load the
 TPU compiler's library at a time, so nothing here may touch it while the
@@ -58,8 +60,19 @@ def topo():
     compilation_cache.reset_cache()
 
 
+# instruction-name substrings the benchmark's roofline readers match
+KERNEL_NAMES = {"decode": "paged_decode", "chunk": "paged_prefill"}
+
+
 def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _kernel_instructions(text: str) -> list:
+    """Instruction names of the Mosaic kernel calls in compiled HLO text."""
+    return [line.split(" = ", 1)[0].strip().lstrip("%")
+            for line in text.splitlines()
+            if "custom-call(" in line and "tpu_custom_call" in line]
 
 
 def _pool_args(sds, Hkv, hd, int8):
@@ -98,7 +111,8 @@ def test_paged_kernel_compiles_for_v5e(topo, model, kernel, int8):
         args = [sds((C, Hkv * G, hd), jnp.bfloat16), *pools,
                 sds((NB,), jnp.int32), sds((C, Hkv, hd), jnp.bfloat16),
                 sds((C, Hkv, hd), jnp.bfloat16), *scales]
-    assert "tpu_custom_call" in _compiled_text(fn, *args)
+    names = _kernel_instructions(_compiled_text(fn, *args))
+    assert names and all(KERNEL_NAMES[kernel] in n for n in names), names
 
 
 @pytest.mark.parametrize("split", ["head", "block"])
@@ -130,5 +144,5 @@ def test_paged_split_compiles_for_four_v5e_chips(topo, split):
         local = sds((4, B, NB // 4), jnp.int32,
                     NamedSharding(mesh, P("attn")))
         args = (q, kp, kp, local, local, cl)
-    text = _compiled_text(fn, *args)
-    assert "tpu_custom_call" in text
+    names = _kernel_instructions(_compiled_text(fn, *args))
+    assert names and all(KERNEL_NAMES["decode"] in n for n in names), names
