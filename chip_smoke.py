@@ -7,8 +7,9 @@ One process, no fallback: on any platform other than the TPU it exits
 non-zero before doing anything. With no option it
 
   1. runs the paged decode and prefill-chunk kernels (bf16 and int8 pools)
-     at tinyllama-1.1b serving shapes and compares each with its jnp
-     reference, computed in fp32 at the highest matmul precision;
+     at tinyllama-1.1b serving shapes, and the decode kernel at a
+     mistral-nemo-12b worker's 128-lane heads, and compares each with its
+     jnp reference, computed in fp32 at the highest matmul precision;
   2. makes the served KV pool, bf16 and int8, and checks that the int8
      pool holds no more of the device than its element bytes say;
   3. serves 8 azure-conv requests with tinyllama-1.1b at its published
@@ -38,6 +39,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 # tinyllama-1.1b attention widths (configs/tinyllama_1_1b.py) at the
 # engine's serving block size
 HKV, G, HD, BS = 4, 8, 64, 16
+# a mistral-nemo-12b attention worker's share (4 of 8 KV heads, 128 lanes):
+# the decode kernel copies these pool blocks itself, many a grid step
+WIDE = (4, 4, 128)
 # Stale pool rows (past cache_len, pad slots) hold these: a dropped mask
 # lets them into the softmax and moves the output by orders of magnitude.
 POISON_K, POISON_V = 8.0, 1e3
@@ -94,16 +98,16 @@ class CompileLog:
 # ---------------------------------------------------------------------------
 # phase 1: kernels against their fp32 references
 # ---------------------------------------------------------------------------
-def _pools(rng, num_blocks: int, int8: bool):
+def _pools(rng, num_blocks: int, int8: bool, hkv: int = HKV, hd: int = HD):
     """Random K/V pools (bf16, or int8 values with fp32 per-token scales)."""
     import jax.numpy as jnp
 
-    shape = (HKV, num_blocks, BS, HD)
+    shape = (hkv, num_blocks, BS, hd)
     if not int8:
         return (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16),
                 jnp.asarray(rng.standard_normal(shape), jnp.bfloat16),
                 None, None)
-    sshape = (HKV, num_blocks, 1, BS)
+    sshape = (hkv, num_blocks, 1, BS)
     return (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
             jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
             jnp.asarray(rng.uniform(0.005, 0.02, sshape), jnp.float32),
@@ -148,18 +152,21 @@ def _close(name: str, got, want) -> None:
     assert err <= tol, f"{name}: max_err {err:.3e} > tol {tol:.3e}"
 
 
-def check_decode(int8: bool, *, interpret: bool = False, seed: int = 0):
-    """Paged decode: B=8 ragged sequences of up to nb=24 blocks over a
-    512-block pool; stale tail rows and pad slots poisoned."""
+def check_decode(int8: bool, *, interpret: bool = False, seed: int = 0,
+                 widths=(HKV, G, HD), nb: int = 24):
+    """Paged decode: B=8 ragged sequences of up to nb blocks over a pool
+    of at least 512 blocks; stale tail rows and pad slots poisoned."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.kernels import paged_decode_attention as pda
 
+    hkv, g, hd = widths
     rng = np.random.default_rng(seed)
-    B, nb, num_blocks = 8, 24, 512
-    kp, vp, ks, vs = _pools(rng, num_blocks, int8)
+    B = 8
+    num_blocks = max(512, B * nb + 1)
+    kp, vp, ks, vs = _pools(rng, num_blocks, int8, hkv, hd)
     lens = rng.integers(nb * BS // 3, nb * BS + 1, B)
     lens[0] = nb * BS                  # one full table
     lens[1] = 5 * BS + 3               # short, partial tail, 18 pad slots
@@ -172,14 +179,21 @@ def check_decode(int8: bool, *, interpret: bool = False, seed: int = 0):
         if lens[b] % BS:
             stale.append((int(ids[b, live - 1]), int(lens[b]) % BS))
     kp, vp, ks, vs = _poison(kp, vp, ks, vs, stale)
-    q = jnp.asarray(rng.standard_normal((B, HKV, G, HD)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((B, hkv, g, hd)), jnp.bfloat16)
     bt, cl = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
     got = pda.paged_decode_attention(q, kp, vp, bt, cl, k_scale=ks,
                                      v_scale=vs, interpret=interpret)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(pda.paged_decode_attention_jnp)(
             *_f32(q, kp, vp), bt, cl, k_scale=ks, v_scale=vs)
-    _close(f"decode_{'int8' if int8 else 'bf16'}", got, want)
+    _close(f"decode_{'int8' if int8 else 'bf16'}_hd{hd}_nb{nb}", got, want)
+
+
+def check_decode_wide(int8: bool, *, interpret: bool = False):
+    """Paged decode at 128 lanes, walking 150 blocks: the bf16 kernel
+    copies them by hand in chunks (two whole, one ragged at 16 tokens a
+    block), the int8 one a block a step."""
+    check_decode(int8, interpret=interpret, seed=2, widths=WIDE, nb=150)
 
 
 def check_chunk(int8: bool, *, interpret: bool = False, seed: int = 1):
@@ -212,6 +226,7 @@ def check_chunk(int8: bool, *, interpret: bool = False, seed: int = 1):
 def kernel_phase(*, interpret: bool = False) -> None:
     for int8 in (False, True):
         check_decode(int8, interpret=interpret)
+        check_decode_wide(int8, interpret=interpret)
         check_chunk(int8, interpret=interpret)
 
 

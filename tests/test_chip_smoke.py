@@ -34,7 +34,8 @@ def test_refuses_cpu_and_names_it():
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("check", ["check_decode", "check_chunk"])
+@pytest.mark.parametrize("check", ["check_decode", "check_chunk",
+                                   "check_decode_wide"])
 def test_kernel_check_passes_interpreted(smoke, check, int8):
     getattr(smoke, check)(int8, interpret=True)
 

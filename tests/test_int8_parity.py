@@ -70,53 +70,56 @@ class _Ref:
 
 
 class _PID:
-    """Context manager patching pl.program_id to the replayed grid cell
-    (pl.when natively accepts the resulting python-bool conditions)."""
+    """Context manager patching pl.program_id to the replayed grid cell,
+    and pl.when to run its body eagerly: the replayed conditions are
+    concrete (python bools, or arrays computed from the scalar operands)."""
 
     def __init__(self):
         self.ids = (0, 0, 0)
 
     def __enter__(self):
-        self._orig = pl.program_id
+        self._orig = pl.program_id, pl.when
         pl.program_id = lambda i: self.ids[i]
+        pl.when = lambda cond: lambda f: f() if bool(cond) else None
         return self
 
     def __exit__(self, *a):
-        pl.program_id = self._orig
+        pl.program_id, pl.when = self._orig
 
 
 def replay_decode(q, k_pool, v_pool, ks, vs, bt, bp, cl, **kw):
-    """Drive _paged_decode_kernel_int8 per (b, h, kb) grid cell, eagerly,
-    feeding exactly the operand tiles the BlockSpecs would map in."""
+    """Drive the int8 decode kernel (one pool block a grid step, the grid
+    pipeline doing the copies) per (b, slot) grid cell, eagerly, feeding
+    exactly the operand tiles the BlockSpecs would map in."""
     B, Hkv, G, hd = q.shape
     bs = k_pool.shape[2]
     nb = bt.shape[1]
-    kern = functools.partial(pda._paged_decode_kernel_int8,
-                             block_size=bs, nb=nb,
+    assert pda.decode_blocks_per_step(bs, hd, Hkv, nb, 1, quantized=True) == 1
+    kern = functools.partial(pda._paged_decode_kernel, P=1, by_hand=False,
+                             quantized=True, contiguous=True, block_size=bs,
+                             nb=nb,
                              sliding_window=kw.get("sliding_window", 0),
                              attention_sinks=kw.get("attention_sinks", 0),
                              logit_softcap=kw.get("logit_softcap", 0.0))
     o = jnp.zeros((B, Hkv, G, hd), q.dtype)
     with _PID() as pid:
         for b in range(B):
-            for h in range(Hkv):
-                acc = _Ref(jnp.zeros((G, hd), jnp.float32))
-                m = _Ref(jnp.zeros((G, 128), jnp.float32))
-                ell = _Ref(jnp.zeros((G, 128), jnp.float32))
-                o_r = _Ref(jnp.zeros((1, 1, G, hd), q.dtype))
-                lo_r = _Ref(jnp.zeros((1, 1, G, 128), jnp.float32))
-                mo_r = _Ref(jnp.zeros((1, 1, G, 128), jnp.float32))
-                for kb in range(nb):
-                    pid.ids = (b, h, kb)
-                    blk = int(bt[b, kb])
-                    kern(_Ref(bt), _Ref(bp), _Ref(cl),
-                         _Ref(q[b:b + 1, h:h + 1]),
-                         _Ref(k_pool[h:h + 1, blk:blk + 1]),
-                         _Ref(v_pool[h:h + 1, blk:blk + 1]),
-                         _Ref(ks[h:h + 1, blk:blk + 1]),
-                         _Ref(vs[h:h + 1, blk:blk + 1]),
-                         o_r, lo_r, mo_r, acc, m, ell)
-                o = o.at[b, h].set(o_r.a[0, 0])
+            acc = _Ref(jnp.zeros((Hkv, G, hd), jnp.float32))
+            m = _Ref(jnp.zeros((Hkv, G, 128), jnp.float32))
+            ell = _Ref(jnp.zeros((Hkv, G, 128), jnp.float32))
+            o_r = _Ref(jnp.zeros((Hkv, G, hd), q.dtype))
+            lo_r = _Ref(jnp.zeros((Hkv, G, 128), jnp.float32))
+            mo_r = _Ref(jnp.zeros((Hkv, G, 128), jnp.float32))
+            walk = min(nb, -(-int(cl[b]) // bs))
+            for kb in range(nb):
+                pid.ids = (b, kb)
+                # slots past the row's walk map its last block in again
+                blk = int(bt[b, max(min(kb, walk - 1), 0)])
+                kern(_Ref(bt), _Ref(bp), _Ref(cl), _Ref(q[b]),
+                     _Ref(k_pool[:, blk]), _Ref(v_pool[:, blk]),
+                     _Ref(ks[:, blk]), _Ref(vs[:, blk]),
+                     o_r, lo_r, mo_r, acc, m, ell)
+            o = o.at[b].set(o_r.a)
     return o
 
 

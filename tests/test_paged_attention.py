@@ -11,6 +11,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 from repro.configs import registry
 from repro.core import combine as C
+from repro.kernels import paged_decode_attention as pda
 from repro.kernels import ref
 from repro.kernels.paged_decode_attention import (paged_decode_attention,
                                                  paged_decode_attention_jnp,
@@ -40,6 +41,8 @@ def _rand_paged(seed, B, Hkv, G, hd, bs, nb, spare_blocks=3):
     (3, 4, 8, 128, 8, 5),       # many small blocks
     (2, 8, 2, 128, 32, 2),
     (1, 2, 16, 64, 16, 7),      # big GQA group, ragged
+    (3, 2, 4, 128, 16, 140),    # several chunks of copied blocks, ragged
+    (2, 1, 16, 128, 8, 150),    # one KV head (a glm4-9b worker), bs 8
 ])
 def test_paged_kernel_matches_dense_oracle(B, Hkv, G, hd, bs, nb):
     q, kp, vp, bt, clen = _rand_paged(B * hd + nb, B, Hkv, G, hd, bs, nb)
@@ -67,6 +70,117 @@ def test_paged_kernel_window_sinks_softcap(sw, sinks, cap):
                                           attention_sinks=sinks,
                                           logit_softcap=cap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The chunked walk: a 128-lane head is copied by hand, P blocks a grid step
+# (P = CHUNK_TOKENS / 16 at block 16 here), so nb = 2P + 6 walks two whole
+# chunks and a ragged third.
+# ---------------------------------------------------------------------------
+def _chunk_pools(seed, B, Hkv, G, hd, bs, nb, kv):
+    """Pools for the chunked walk: distinct random blocks, stale NaN in
+    every block no table uses, in ``kv`` ('f32', 'bf16' or 'int8' with
+    per-token scales)."""
+    NB = B * nb + 5
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(ks[0], (B, Hkv, G, hd))
+    bt = jax.random.permutation(ks[3], NB)[:B * nb].reshape(B, nb)
+    bt = bt.astype(jnp.int32)
+    unused = jnp.ones((NB,), bool).at[bt.reshape(-1)].set(False)
+    scales = {}
+    if kv == "int8":
+        kp = jax.random.randint(ks[1], (Hkv, NB, bs, hd), -127, 128,
+                                jnp.int32).astype(jnp.int8)
+        vp = jax.random.randint(ks[2], (Hkv, NB, bs, hd), -127, 128,
+                                jnp.int32).astype(jnp.int8)
+        sc = [jax.random.uniform(k, (Hkv, NB, 1, bs), jnp.float32, 1e-3, 0.1)
+              for k in ks[4:]]
+        # stale scales poison nothing either: masked rows read exact zeros
+        sc = [jnp.where(unused[None, :, None, None], jnp.nan, x) for x in sc]
+        scales = {"k_scale": sc[0], "v_scale": sc[1]}
+    else:
+        dt = jnp.float32 if kv == "f32" else jnp.bfloat16
+        kp = jax.random.normal(ks[1], (Hkv, NB, bs, hd)).astype(dt)
+        vp = jax.random.normal(ks[2], (Hkv, NB, bs, hd)).astype(dt)
+        pad = unused[None, :, None, None]
+        kp, vp = jnp.where(pad, jnp.nan, kp), jnp.where(pad, jnp.nan, vp)
+    return q, kp, vp, bt, scales
+
+
+def _edge_lens(bs, nb, P):
+    """One token; exactly a block boundary; exactly a chunk boundary; the
+    full table."""
+    return jnp.array([1, 3 * bs, P * bs, nb * bs], jnp.int32)
+
+
+CHUNK = pda.CHUNK_TOKENS // 16   # blocks of 16 a step where copied by hand
+
+
+@pytest.mark.parametrize("Hkv,G,sw,sinks,cap,kv", [
+    (2, 4, 0, 0, 0.0, "f32"),          # nb above P, not a multiple of it
+    (4, 4, 0, 0, 0.0, "bf16"),         # a mistral-nemo-12b worker's heads
+    (1, 16, 0, 0, 0.0, "bf16"),        # a glm4-9b worker: one KV head, G 16
+    (2, 4, 1000, 0, 0.0, "f32"),       # window edge inside the second chunk
+    (2, 4, 1000, 5, 30.0, "f32"),      # + sinks in the first, softcap
+    (2, 4, 40, 3, 0.0, "bf16"),        # window inside one block
+    (2, 4, 0, 0, 0.0, "int8"),         # int8 pools: one block a step
+    (2, 4, 1000, 5, 30.0, "int8"),
+])
+def test_paged_kernel_chunked_walk(Hkv, G, sw, sinks, cap, kv):
+    """Rows of 1 token, a block boundary, a chunk boundary and the full
+    table in one batch, across chunk boundaries, against the jnp paged
+    reference and the dense oracle; NaN in unused pool blocks (and in
+    their int8 scales) must not reach any output."""
+    B, hd, bs, nb = 4, 128, 16, 2 * CHUNK + 6
+    q, kp, vp, bt, scales = _chunk_pools(Hkv * G, B, Hkv, G, hd, bs, nb, kv)
+    itemsize = jnp.dtype(kp.dtype).itemsize
+    P = pda.decode_blocks_per_step(bs, hd, Hkv, nb, itemsize, bool(scales))
+    assert P == (1 if scales else CHUNK)
+    clen = _edge_lens(bs, nb, CHUNK)
+    kw = dict(sliding_window=sw, attention_sinks=sinks, logit_softcap=cap)
+    out = paged_decode_attention(q, kp, vp, bt, clen, interpret=True,
+                                 **scales, **kw)
+    assert np.isfinite(np.asarray(out)).all()
+    want = paged_decode_attention_jnp(q, kp, vp, bt, clen, **scales, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    if not scales:
+        kc, vc = paged_gather_dense(kp, vp, bt)
+        oracle = ref.decode_attention_ref(q, kc, vc, clen, **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("sw,sinks,cap,kv", [
+    (0, 0, 0.0, "f32"), (1000, 5, 30.0, "f32"), (0, 0, 0.0, "bf16"),
+    (0, 0, 0.0, "int8")])
+def test_block_sharded_chunked_walk(sw, sinks, cap, kv):
+    """Block-sharded tables over the chunked walk: 3 shards own a row's
+    blocks round robin (POS_PAD on every foreign slot), a fourth owns
+    none. The first three merge to the full-table answer; the fourth
+    yields the combine identity (l = 0, m = NEG_INF, o = 0)."""
+    B, Hkv, G, hd, bs, nb, n = 4, 2, 4, 128, 16, 2 * CHUNK + 6, 3
+    q, kp, vp, bt, scales = _chunk_pools(17, B, Hkv, G, hd, bs, nb, kv)
+    clen = _edge_lens(bs, nb, CHUNK)
+    kw = dict(sliding_window=sw, attention_sinks=sinks, logit_softcap=cap)
+    want = paged_decode_attention_jnp(q, kp, vp, bt, clen, **scales, **kw)
+    base = jnp.arange(nb, dtype=jnp.int32)[None, :] * bs
+    owner = jnp.arange(nb)[None, :] % n
+    parts = []
+    for s in range(n + 1):
+        pos = jnp.where(owner == s, base, pda.POS_PAD)
+        o, l, m = paged_decode_attention(
+            q, kp, vp, bt, clen, block_positions=pos, interpret=True,
+            return_partials=True, **scales, **kw)
+        if s == n:
+            assert float(jnp.max(l)) == 0.0
+            assert float(jnp.max(jnp.abs(o.astype(jnp.float32)))) == 0.0
+            assert np.all(np.asarray(m) == pda.NEG_INF)
+        parts.append(C.Partial(a=o.astype(jnp.float32) * l[..., None],
+                               s=l, m=m))
+    got = C.finalize(C.combine_many(parts))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
 
