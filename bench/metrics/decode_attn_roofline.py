@@ -1,8 +1,10 @@
-"""The paged decode kernel's share of its roofline: for every call in the
-window, the larger of its operations over the bf16 peak and the live keys,
-values, queries and output over HBM bandwidth, summed, over the kernel's
-device time in the trace. Each call walks the longest row's blocks, so the
-live bytes are less than it reads."""
+"""The paged decode kernel's share of its roofline: for every decode step
+in the window, the larger of its operations over the bf16 peak and the
+live keys, values, queries and output over HBM bandwidth, over every
+layer (the architecture's ``decode_attention``), summed, over the kernel's
+device time in the trace. Each row's walk stops at its own length, in
+whole pool blocks, so the live bytes are at most a block a row short of
+what it reads."""
 from bench import flops
 
 KERNEL = ("paged_decode",)
@@ -13,8 +15,8 @@ def read(run):
         return None
     seconds = run.trace.seconds_matching(KERNEL)
     ideal = sum(flops.roofline_seconds(
-        flops.decode_attention(run.sizes, step.decode_lens), run.peak)
+        run.arch.decode_attention(run.sizes, step.decode_lens), run.peak)
         for step in run.steps if step.decode_lens)
     if not seconds or not ideal:
         return None
-    return 100.0 * run.sizes["num_layers"] * ideal / seconds
+    return 100.0 * ideal / seconds

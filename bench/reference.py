@@ -1,12 +1,12 @@
-"""Plain reference of the dense decoder, in float32, and its control.
+"""Plain reference of the served model, in float32, and its control.
 
-A pre-norm decoder with RMSNorm, grouped-query attention with rotary
-position (the rotate-half form), a SwiGLU feed-forward block and an untied
-output head: the block of Llama and Mistral, as the configuration files in
-``bench/configs`` run it. It imports nothing of the program under test. It
-draws its weights again from the seed (``bench/weights.py``), one layer at
-a time, and runs every sequence through that layer before it draws the
-next, so that the whole model never sits on the device in float32.
+It imports nothing of the program under test. It draws its weights again
+from the seed (``bench/weights.py``), one layer at a time, and runs every
+sequence through that layer, by the architecture's own ``layer_forward``
+(``bench/arch/<arch>.py``), before it draws the next, so that the whole
+model never sits on the device in float32. The head (final RMSNorm and an
+untied output matrix) and the pieces a layer is built from (``mm``,
+``rms_norm``, ``rope``, ``attention``) are here.
 
 Every matrix product runs at ``Precision.HIGHEST``: true float32 on the
 TPU, where the default would take one bfloat16 pass. The control runs the
@@ -56,7 +56,8 @@ def _cast(x: jax.Array, control: bool) -> jax.Array:
     return _fp8(x) if control else x.astype(jnp.float32)
 
 
-def _mm(a, b, control: bool):
+def mm(a, b, control: bool):
+    """a @ b in float32 at HIGHEST; with ``control``, on float8 operands."""
     return jnp.matmul(_cast(a, control), _cast(b, control),
                       precision=HIGHEST)
 
@@ -76,9 +77,10 @@ def rope(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def attention(q, k, v, control: bool):
-    """Causal softmax attention, one block of queries at a time.
-    q: (S, H, h); k, v: (S, K, h). Returns (S, H*h)."""
+def attention(q, k, v, control: bool, window: int = 0):
+    """Causal softmax attention, one block of queries at a time; with a
+    ``window``, each query sees itself and the ``window`` - 1 keys before
+    it. q: (S, H, h); k, v: (S, K, h). Returns (S, H*h)."""
     S, H, h = q.shape
     K = k.shape[1]
     q = q.reshape(S, K, H // K, h) / np.sqrt(h)
@@ -88,8 +90,11 @@ def attention(q, k, v, control: bool):
         qb = _cast(q[s0:s0 + QUERY_BLOCK], control)
         n = qb.shape[0]
         s = jnp.einsum("qkgh,tkh->qkgt", qb, k, precision=HIGHEST)
-        causal = (jnp.arange(S)[None, :] <=
-                  (s0 + jnp.arange(n))[:, None])[:, None, None, :]
+        pos = (s0 + jnp.arange(n))[:, None]
+        causal = jnp.arange(S)[None, :] <= pos
+        if window:
+            causal &= jnp.arange(S)[None, :] > pos - window
+        causal = causal[:, None, None, :]
         p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
         o = jnp.einsum("qkgt,tkh->qkgh", _cast(p, control), v,
                        precision=HIGHEST)
@@ -97,27 +102,10 @@ def attention(q, k, v, control: bool):
     return jnp.concatenate(out, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("sz", "control"))
-def layer_forward(w: Dict, x: jax.Array, sz, control: bool = False):
-    """One decoder layer over one sequence. x: (S, d) float32."""
-    sz = dict(sz)
-    S = x.shape[0]
-    H, K, h = sz["num_heads"], sz["num_kv_heads"], sz["head_dim"]
-    eps, theta = sz["norm_eps"], sz["rope_theta"]
-    a = rms_norm(x, w["norm1"], eps)
-    q = rope(_mm(a, w["wq"], control).reshape(S, H, h), theta)
-    k = rope(_mm(a, w["wk"], control).reshape(S, K, h), theta)
-    v = _mm(a, w["wv"], control).reshape(S, K, h)
-    x = x + _mm(attention(q, k, v, control), w["wo"], control)
-    a = rms_norm(x, w["norm2"], eps)
-    gate = jax.nn.silu(_mm(a, w["w_gate"], control))
-    return x + _mm(gate * _mm(a, w["w_up"], control), w["w_down"], control)
-
-
-@functools.partial(jax.jit, static_argnames=("sz",))
-def _layer_weights(key, l, sz):
+@functools.partial(jax.jit, static_argnames=("sz", "arch", "kind"))
+def _layer_weights(key, l, sz, arch, kind):
     return jax.tree.map(lambda a: a.astype(jnp.float32),
-                        weights.layer(dict(sz), key, l))
+                        weights.layer(arch, dict(sz), key, l, kind=kind))
 
 
 @functools.partial(jax.jit, static_argnames=("sz", "name"))
@@ -128,14 +116,14 @@ def _global(key, sz, name):
 @functools.partial(jax.jit, static_argnames=("eps", "control"))
 def _head_rows(x, final_norm, lm_head, eps: float, control: bool):
     """Logits of some rows: (n, d) -> (n, V) float32."""
-    return _mm(rms_norm(x, final_norm, eps), lm_head, control)
+    return mm(rms_norm(x, final_norm, eps), lm_head, control)
 
 
 def _frozen(sz: Dict) -> Tuple:
     return tuple(sorted(sz.items()))
 
 
-def hidden_states(sz: Dict, key, seqs: Sequence[np.ndarray],
+def hidden_states(arch, sz: Dict, key, seqs: Sequence[np.ndarray],
                   control: bool = False) -> List[jax.Array]:
     """Final hidden states (before the last norm) of each token sequence,
     each padded at its end to a bucket length (padding follows every real
@@ -149,8 +137,8 @@ def hidden_states(sz: Dict, key, seqs: Sequence[np.ndarray],
         xs.append(embed[jnp.asarray(pad)])
     del embed
     for l in range(sz["num_layers"]):
-        w = _layer_weights(key, l, fz)
-        xs = [layer_forward(w, x, fz, control) for x in xs]
+        w = _layer_weights(key, l, fz, arch, arch.kind(sz, l))
+        xs = [arch.layer_forward(w, x, fz, l, control) for x in xs]
         del w
     return xs
 
@@ -191,7 +179,7 @@ def served_rows(prompt: np.ndarray, served: Sequence[int]
     return toks, rows, np.asarray(served, np.int32)
 
 
-def compare(sz: Dict, seed: int, requests: Sequence[Tuple[np.ndarray,
+def compare(arch, sz: Dict, seed: int, requests: Sequence[Tuple[np.ndarray,
                                                           Sequence[int]]],
             control: bool = False) -> Dict:
     """The widest gap by which a served token's logit lies below the
@@ -204,11 +192,11 @@ def compare(sz: Dict, seed: int, requests: Sequence[Tuple[np.ndarray,
     rows = [r for _, r, _ in parts]
     out = {"tokens": int(sum(len(r) for r in rows))}
     if control:
-        xs = hidden_states(sz, key, seqs, control=True)
+        xs = hidden_states(arch, sz, key, seqs, control=True)
         picks = [jnp.argmax(lg, axis=1).astype(jnp.int32)
                  for lg in head_rows(sz, key, xs, rows, control=True)]
         del xs
-    xs = hidden_states(sz, key, seqs)
+    xs = hidden_states(arch, sz, key, seqs)
     ref = head_rows(sz, key, xs, rows)
     del xs
     out["max_gap"] = max(float(jnp.max(_gaps(lg, jnp.asarray(t))))

@@ -46,6 +46,7 @@ WARM_WINDOWS = 1.5
 @dataclasses.dataclass
 class Run:
     """What the per-layer readers read."""
+    arch: object             # the cell's bench/arch/<arch>.py
     sizes: dict
     peak: dict
     window_s: float
@@ -178,9 +179,10 @@ def execute(cell, seed: int, seconds: float, traced: bool, devices,
     compiles = compiles or compilelog.CompileLog()
     eng_conf, mix = cell.engine, cell.traffic
     lists = loadgen.closed_loop(mix, sizes["vocab"], seed)
-    params = system.make_params(sizes, seed)
+    params = system.make_params(cell.arch, sizes, seed)
     jax.block_until_ready(params)
-    engine = system.build_engine(cell.config["name"], sizes, eng_conf, params)
+    engine = system.build_engine(cell.arch, cell.config["name"], sizes,
+                                 eng_conf, params)
     annotate = jax.profiler.TraceAnnotation if traced else None
 
     def new_loop():
@@ -232,7 +234,7 @@ def execute(cell, seed: int, seconds: float, traced: bool, devices,
     if traced:
         device["busy_s"] = reduced.busy_s
         device["window_s"] = reduced.window_s
-        run = Run(sizes, peak, t1 - t0, steps, in_window, reduced)
+        run = Run(cell.arch, sizes, peak, t1 - t0, steps, in_window, reduced)
         for m in cell.per_layer:
             value = spec.reader(m.name)(run)
             if value is not None:
@@ -250,7 +252,8 @@ def execute(cell, seed: int, seconds: float, traced: bool, devices,
     del loop, engine, params, steps
     gc.collect()
     t = time.perf_counter()
-    got = reference.compare(sizes, seed, picked, control=control)
+    got = reference.compare(cell.arch, sizes, seed, picked,
+                            control=control)
     limit = check_conf["max_gap"]
     correct = judge(got["max_gap"], limit)
     log(f"reference: {len(picked)} requests, {got['tokens']} served tokens "

@@ -4,14 +4,18 @@ Everything that belongs to one configuration, one traffic mix, one cell or
 one per-layer metric sits in a file of its own, found by the name that
 ``BENCHMARK.json`` gives it:
 
-    bench/configs/<config>.json    model sizes, as run, beside their source
+    bench/configs/<config>.json    model sizes, as run, beside their source,
+                                   and the name of their ``"arch"``
+    bench/arch/<arch>.py           the block: program layout, weights,
+                                   float32 reference, counts (see
+                                   ``bench/arch/dense.py``)
     bench/traffic/<traffic>.json   parameters for ``bench/loadgen.py``
     bench/cells/<workload>.json    the engine settings of one cell
     bench/metrics/<metric>.py      a reader with ``read(run) -> float|None``
 
-So a cell, a configuration or a metric is added by adding files and
-entries, never by editing a file that is already there. Nothing here
-imports JAX or touches a device.
+So a cell, a configuration, an architecture or a metric is added by adding
+files and entries, never by editing a file that is already there. Nothing
+here touches a device; loading an architecture imports JAX.
 """
 from __future__ import annotations
 
@@ -41,6 +45,7 @@ class Cell:
     name: str
     chips: int
     config: Dict           # bench/configs/<config>.json
+    arch: object           # bench/arch/<config["arch"]>.py, loaded
     traffic: Dict          # bench/traffic/<traffic>.json
     engine: Dict           # bench/cells/<name>.json
     end_to_end: List[Metric]
@@ -81,25 +86,36 @@ def resolve(workload: str, root: Path = ROOT) -> Cell:
     layer = [_metric(m) for m in spec["per_layer"]]
     layer = [m for m in layer if m.applies_to(workload) and
              m.moves in reported]
-    return Cell(name=workload, chips=int(entry["chips"]),
-                config=_load_json(root / conf["file"]),
+    config = _load_json(root / conf["file"])
+    return Cell(name=workload, chips=int(entry["chips"]), config=config,
+                arch=arch(config["arch"], root),
                 traffic=_load_json(bench / "traffic" /
                                    f"{entry['traffic']}.json"),
                 engine=_load_json(bench / "cells" / f"{workload}.json"),
                 end_to_end=e2e, per_layer=layer)
 
 
+def _load_module(kind: str, name: str, root: Path):
+    path = root / "bench" / kind / f"{name}.py"
+    mod_name = f"bench_{kind}_" + name.replace(".", "_").replace("-", "_")
+    mod_spec = importlib.util.spec_from_file_location(mod_name, path)
+    if mod_spec is None or not path.exists():
+        raise FileNotFoundError(f"no {kind} module for {name!r}: {path}")
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str, root: Path = ROOT) -> Callable:
     """``read`` of ``bench/metrics/<metric>.py``: takes the finished run
     and returns the metric's value, or None where it found nothing."""
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    mod_name = "bench_metric_" + metric.replace(".", "_").replace("-", "_")
-    mod_spec = importlib.util.spec_from_file_location(mod_name, path)
-    if mod_spec is None or not path.exists():
-        raise FileNotFoundError(f"no reader for metric {metric!r}: {path}")
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module("metrics", metric, root).read
+
+
+def arch(name: str, root: Path = ROOT):
+    """The module ``bench/arch/<name>.py``: what the harness knows of one
+    architecture's block (``bench/arch/dense.py`` lists it)."""
+    return _load_module("arch", name, root)
 
 
 def model_sizes(config: Dict) -> Dict:
