@@ -17,6 +17,7 @@ from repro.kernels import decode_attention as _da
 from repro.kernels import paged_decode_attention as _pda
 from repro.kernels import paged_prefill_attention as _ppa
 from repro.kernels import rwkv6_scan as _rw
+from repro.kernels import ssm_decode_update as _ssd
 from repro.kernels import ssm_scan as _ssm
 
 
@@ -80,8 +81,22 @@ def rwkv6_scan(r, k, v, w, u, *, chunk: int = 128):
 
 
 def ssm_scan(x, B_in, C_in, decay, *, chunk: int = 128):
-    return _ssm.ssm_scan(x, B_in, C_in, decay, chunk=chunk,
-                         interpret=interpret_mode())
+    """x (B, S, H, P) dt-scaled; B_in/C_in (B, S, G, N) by group; decay
+    (B, S, H). y from a zero state."""
+    y, _ = _ssm.ssm_chunk_scan(x, jnp.log(decay), B_in, C_in, chunk=chunk,
+                               interpret=interpret_mode())
+    return y
+
+
+def ssm_chunk_scan(xdt, log_decay, B_in, C_in, state0=None, *,
+                   chunk: int = 128):
+    return _ssm.ssm_chunk_scan(xdt, log_decay, B_in, C_in, state0,
+                               chunk=chunk, interpret=interpret_mode())
+
+
+def ssm_decode_update(state, slots, xdt, decay, B_in, C_in, *, layer: int):
+    return _ssd.ssm_decode_update(state, slots, xdt, decay, B_in, C_in,
+                                  layer=layer, interpret=interpret_mode())
 
 
 # --- register the Pallas decode backend with the model layer --------------

@@ -40,7 +40,8 @@ def init_attention(key, cfg: ModelConfig, dtype=None) -> Dict[str, jax.Array]:
 
 def qkv_project(params, cfg: ModelConfig, x: jax.Array,
                 positions: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """x: (B, S, d) -> q (B,S,H,hd), k/v (B,S,Hkv,hd) with RoPE applied."""
+    """x: (B, S, d) -> q (B,S,H,hd), k/v (B,S,Hkv,hd) with RoPE applied
+    (none where ``rope_theta`` is 0)."""
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
@@ -48,8 +49,9 @@ def qkv_project(params, cfg: ModelConfig, x: jax.Array,
         from repro.models.common import rms_norm
         q = rms_norm(q, params["q_norm"])
         k = rms_norm(k, params["k_norm"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope_theta:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 

@@ -183,22 +183,17 @@ def _mamba_final_state(mp: Dict, cfg: ModelConfig, h: jax.Array) -> Dict:
     def step(hs, inp):
         xdt_t, B_t, C_t, decay_t = inp
         hs = hs * decay_t[:, :, None, None] + \
-            xdt_t[..., None] * B_t[:, None, None, :]
+            xdt_t[..., None] * B_t[:, :, None, :]
         return hs, None
 
     h0 = jnp.zeros((B_, H, P, N), jnp.float32)
-    xs = (xdt.transpose(1, 0, 2, 3), Bm.astype(jnp.float32).transpose(1, 0, 2),
-          Cm.astype(jnp.float32).transpose(1, 0, 2), decay.transpose(1, 0, 2))
+    xs = (xdt.transpose(1, 0, 2, 3),
+          ssm._per_head(Bm.astype(jnp.float32), H).transpose(1, 0, 2, 3),
+          ssm._per_head(Cm.astype(jnp.float32), H).transpose(1, 0, 2, 3),
+          decay.transpose(1, 0, 2))
     h_fin, _ = jax.lax.scan(step, h0, xs)
     # conv state: last K-1 *pre-activation* conv inputs
-    proj = jnp.einsum("bsd,de->bse", h, mp["w_in"])
-    _, xr, Bm2, Cm2, _ = jnp.split(
-        proj, [d_inner, 2 * d_inner, 2 * d_inner + N, 2 * d_inner + 2 * N],
-        axis=-1)
-    conv_in = jnp.concatenate([xr, Bm2, Cm2], axis=-1)
-    K = cfg.ssm_conv
-    pad = jnp.pad(conv_in, ((0, 0), (K - 1, 0), (0, 0)))
-    return {"h": h_fin, "conv": pad[:, -(K - 1):, :]}
+    return {"h": h_fin, "conv": conv_state}
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,9 @@ import jax.numpy as jnp
 
 Params = Dict[str, Any]
 
+# layer kinds of a ``mixed`` stack's ``layer_pattern``
+MAMBA, MLP, ATTENTION = "M", "-", "*"
+
 
 # ---------------------------------------------------------------------------
 # Config
@@ -24,7 +27,7 @@ class ModelConfig:
     """One config describes every supported family; unused fields stay 0."""
 
     name: str = "model"
-    family: str = "dense"  # dense | moe | ssm | hybrid | vlm | audio
+    family: str = "dense"  # dense | moe | ssm | hybrid | mixed | vlm | audio
     num_layers: int = 2
     d_model: int = 256
     num_heads: int = 4
@@ -53,7 +56,7 @@ class ModelConfig:
     local_global: bool = False  # gemma2-style alternating local/global
     attn_logit_softcap: float = 0.0
     final_logit_softcap: float = 0.0
-    rope_theta: float = 10000.0
+    rope_theta: float = 10000.0  # 0 -> attention without rotary position
     qk_norm: bool = False
     post_norms: bool = False  # gemma2 pre+post sandwich norms
     # --- SSM / RWKV ---
@@ -61,9 +64,19 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_conv: int = 4
+    # Mamba heads and B/C groups held here; 0 heads -> ssm_expand·d_model
+    # wide. A chip's share of a model's heads is not expand·d_model wide,
+    # so a share states its heads.
+    ssm_heads: int = 0
+    ssm_groups: int = 1
     rwkv_head_dim: int = 64
     # --- hybrid (zamba2): one shared attention block every `period` layers ---
     shared_attn_period: int = 0
+    # --- mixed: one mixer per layer, its kind given per layer by this
+    # pattern (Nemotron-H's hybrid_override_pattern notation): "M" Mamba-2,
+    # "-" a ReLU-squared MLP, "*" attention; each after a pre-norm, each
+    # followed by a residual add ---
+    layer_pattern: str = ""
     # --- encoder/decoder (seamless) ---
     encoder_layers: int = 0
     is_encoder_decoder: bool = False
@@ -106,6 +119,20 @@ class ModelConfig:
     @property
     def gqa_group(self) -> int:
         return self.num_heads // max(self.num_kv_heads, 1)
+
+    def layers_of(self, kind: str) -> tuple:
+        """Indices of the ``layer_pattern`` layers of one kind."""
+        return tuple(i for i, k in enumerate(self.layer_pattern) if k == kind)
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers that keep keys and values: every layer but for the
+        recurrent families."""
+        if self.family == "mixed":
+            return len(self.layers_of(ATTENTION))
+        if self.family == "hybrid":
+            return self.num_layers // self.shared_attn_period
+        return self.num_layers
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -184,6 +211,12 @@ def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     up = jnp.einsum("...d,df->...f", x, w_up)
     hidden = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
     return jnp.einsum("...f,fd->...d", hidden, w_down)
+
+
+def relu2_mlp(x: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
+    """down(relu(up(x))²): the ungated MLP of Nemotron-H."""
+    h = jax.nn.relu(jnp.einsum("...d,df->...f", x, w_up).astype(jnp.float32))
+    return jnp.einsum("...f,fd->...d", jnp.square(h).astype(x.dtype), w_down)
 
 
 def gelu_mlp(x: jax.Array, w_fc: jax.Array, w_proj: jax.Array) -> jax.Array:

@@ -12,7 +12,7 @@ lerps and LoRA-modulated mixing.
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +24,14 @@ from repro.models.common import ModelConfig, dense_init, rms_norm
 # Mamba2
 # ===========================================================================
 def mamba_dims(cfg: ModelConfig):
-    d_inner = cfg.ssm_expand * cfg.d_model
-    n_heads = d_inner // cfg.ssm_head_dim
-    return d_inner, n_heads, cfg.ssm_head_dim, cfg.ssm_state
+    P = cfg.ssm_head_dim
+    H = cfg.ssm_heads or cfg.ssm_expand * cfg.d_model // P
+    return H * P, H, P, cfg.ssm_state
+
+
+def _conv_channels(cfg: ModelConfig) -> int:
+    d_inner, _, _, N = mamba_dims(cfg)
+    return d_inner + 2 * cfg.ssm_groups * N
 
 
 def init_mamba(key, cfg: ModelConfig, dtype=None) -> Dict:
@@ -34,10 +39,10 @@ def init_mamba(key, cfg: ModelConfig, dtype=None) -> Dict:
     d_inner, H, P, N = mamba_dims(cfg)
     d = cfg.d_model
     ks = jax.random.split(key, 6)
-    conv_ch = d_inner + 2 * N  # conv over x, B, C
+    conv_ch = _conv_channels(cfg)  # conv over x, B, C
     return {
-        # in_proj -> [z (d_inner), x (d_inner), B (N), C (N), dt (H)]
-        "w_in": dense_init(ks[0], (d, 2 * d_inner + 2 * N + H), dtype),
+        # in_proj -> [z (d_inner), x (d_inner), B (G·N), C (G·N), dt (H)]
+        "w_in": dense_init(ks[0], (d, d_inner + conv_ch + H), dtype),
         "conv_w": dense_init(ks[1], (cfg.ssm_conv, conv_ch), dtype, 0.5),
         "conv_b": jnp.zeros((conv_ch,), dtype),
         "a_log": jnp.zeros((H,), jnp.float32),
@@ -48,44 +53,65 @@ def init_mamba(key, cfg: ModelConfig, dtype=None) -> Dict:
     }
 
 
-def _mamba_project(params, cfg, x, conv_state=None):
+def _mamba_project(params, cfg, x, conv_state=None, length=None):
     """Shared pre-recurrence math. x: (B, S, d).
 
-    Returns (z, xh, Bm, Cm, dt, new_conv_state) with
-      z, xh: (B, S, H, P); Bm, Cm: (B, S, N); dt: (B, S, H).
+    Returns (z, xh, Bm, Cm, dt, new_conv_state) with z, xh: (B, S, H, P);
+    Bm, Cm: (B, S, G, N); dt: (B, S, H) fp32. ``conv_state`` (B, K-1,
+    conv_ch) holds the pre-activation conv inputs of the K-1 tokens before
+    x (None: the sequence starts here); the new one holds those of x's
+    last K-1 tokens, or of its first ``length`` tokens' last K-1 where x
+    is padded past ``length`` (a traced count).
     """
     d_inner, H, P, N = mamba_dims(cfg)
+    GN = cfg.ssm_groups * N
     proj = jnp.einsum("bsd,de->bse", x, params["w_in"])
-    z, xr, Bm, Cm, dt = jnp.split(
-        proj, [d_inner, 2 * d_inner, 2 * d_inner + N, 2 * d_inner + 2 * N],
-        axis=-1)
+    z, conv_in, dt = jnp.split(proj, [d_inner, 2 * d_inner + 2 * GN],
+                               axis=-1)
     # causal depthwise conv over (x, B, C)
-    conv_in = jnp.concatenate([xr, Bm, Cm], axis=-1)  # (B, S, conv_ch)
     K = cfg.ssm_conv
-    if conv_state is None:  # full sequence: pad left
-        padded = jnp.pad(conv_in, ((0, 0), (K - 1, 0), (0, 0)))
-        new_conv_state = conv_in[:, -(K - 1):, :] if conv_in.shape[1] >= K - 1 \
-            else padded[:, -(K - 1):, :]
-    else:  # decode: prepend cached last K-1 inputs
-        padded = jnp.concatenate([conv_state, conv_in], axis=1)
+    if conv_state is None:  # the sequence starts here: pad left
+        conv_state = jnp.zeros(conv_in.shape[:1] + (K - 1,)
+                               + conv_in.shape[2:], conv_in.dtype)
+    padded = jnp.concatenate([conv_state.astype(conv_in.dtype), conv_in],
+                             axis=1)
+    if length is None:
         new_conv_state = padded[:, -(K - 1):, :]
+    else:
+        new_conv_state = jax.lax.dynamic_slice_in_dim(padded, length, K - 1,
+                                                      axis=1)
     conv = sum(padded[:, i:i + conv_in.shape[1], :] * params["conv_w"][i]
                for i in range(K)) + params["conv_b"]
     conv = jax.nn.silu(conv.astype(jnp.float32)).astype(x.dtype)
-    xr, Bm, Cm = jnp.split(conv, [d_inner, d_inner + N], axis=-1)
+    xr, Bm, Cm = jnp.split(conv, [d_inner, d_inner + GN], axis=-1)
     B_, S = x.shape[0], x.shape[1]
     xh = xr.reshape(B_, S, H, P)
     z = z.reshape(B_, S, H, P)
+    Bm = Bm.reshape(B_, S, cfg.ssm_groups, N)
+    Cm = Cm.reshape(B_, S, cfg.ssm_groups, N)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     return z, xh, Bm, Cm, dt, new_conv_state
 
 
+def _per_head(m: jax.Array, H: int) -> jax.Array:
+    """(…, G, N) group rows -> (…, H, N), head h reading group h·G/H."""
+    return jnp.repeat(m, H // m.shape[-2], axis=-2)
+
+
 def _mamba_finish(params, cfg, y, z, B_, S):
+    """Gate, then RMSNorm over each group's d_inner/G channels (Mamba-2's
+    RMSNormGated with the norm after the gate), then the out projection."""
     d_inner, H, P, N = mamba_dims(cfg)
+    G = cfg.ssm_groups
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
-    y = y.reshape(B_, S, d_inner)
-    y = rms_norm(y, params["out_norm"], cfg.norm_eps)
-    return jnp.einsum("bse,ed->bsd", y, params["w_out"])
+    y = y.reshape(B_, S, G, d_inner // G)
+    y = rms_norm(y, params["out_norm"].reshape(G, d_inner // G), cfg.norm_eps)
+    return jnp.einsum("bse,ed->bsd", y.reshape(B_, S, d_inner),
+                      params["w_out"])
+
+
+def _skip(params, y, xh):
+    return y + xh.astype(jnp.float32) * params["d_skip"][None, None, :, None]
 
 
 def mamba_forward(params: Dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
@@ -100,32 +126,33 @@ def mamba_forward(params: Dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
         from repro.kernels import ops as kops
         y = kops.ssm_scan(xdt, Bm.astype(jnp.float32),
                           Cm.astype(jnp.float32), decay)
-        y = y + xh.astype(jnp.float32) * params["d_skip"][None, None, :, None]
+        y = _skip(params, y, xh)
         return _mamba_finish(params, cfg, y.astype(x.dtype), z, B_, S)
 
     def step(h, inp):
         xdt_t, B_t, C_t, decay_t = inp
         # h: (B, H, P, N)
         h = h * decay_t[:, :, None, None] + \
-            xdt_t[..., None] * B_t[:, None, None, :]
-        y = jnp.einsum("bhpn,bn->bhp", h, C_t)
+            xdt_t[..., None] * B_t[:, :, None, :]
+        y = jnp.einsum("bhpn,bhn->bhp", h, C_t)
         return h, y
 
     h0 = jnp.zeros((B_, H, P, N), jnp.float32)
-    xs = (xdt.transpose(1, 0, 2, 3), Bm.astype(jnp.float32).transpose(1, 0, 2),
-          Cm.astype(jnp.float32).transpose(1, 0, 2), decay.transpose(1, 0, 2))
+    xs = (xdt.transpose(1, 0, 2, 3),
+          _per_head(Bm.astype(jnp.float32), H).transpose(1, 0, 2, 3),
+          _per_head(Cm.astype(jnp.float32), H).transpose(1, 0, 2, 3),
+          decay.transpose(1, 0, 2))
     _, ys = jax.lax.scan(step, h0, xs)  # ys: (S, B, H, P)
-    y = ys.transpose(1, 0, 2, 3) + \
-        xh.astype(jnp.float32) * params["d_skip"][None, None, :, None]
+    y = _skip(params, ys.transpose(1, 0, 2, 3), xh)
     return _mamba_finish(params, cfg, y.astype(x.dtype), z, B_, S)
 
 
 def init_mamba_state(cfg: ModelConfig, batch: int) -> Dict:
     d_inner, H, P, N = mamba_dims(cfg)
-    conv_ch = d_inner + 2 * N
     return {
         "h": jnp.zeros((batch, H, P, N), jnp.float32),
-        "conv": jnp.zeros((batch, cfg.ssm_conv - 1, conv_ch), cfg.dtype),
+        "conv": jnp.zeros((batch, cfg.ssm_conv - 1, _conv_channels(cfg)),
+                          cfg.dtype),
     }
 
 
@@ -133,16 +160,84 @@ def mamba_decode_step(params: Dict, cfg: ModelConfig, x: jax.Array,
                       state: Dict) -> Tuple[jax.Array, Dict]:
     """x: (B, 1, d) -> (y (B,1,d), new_state)."""
     B_, S, _ = x.shape
+    d_inner, H, P, N = mamba_dims(cfg)
     z, xh, Bm, Cm, dt, conv_state = _mamba_project(
         params, cfg, x, conv_state=state["conv"])
     decay = jnp.exp(-jnp.exp(params["a_log"]) * dt)  # (B, 1, H)
     h = state["h"] * decay[:, 0, :, None, None] + \
         (xh.astype(jnp.float32) * dt[..., None])[:, 0, ..., None] * \
-        Bm.astype(jnp.float32)[:, 0, None, None, :]
-    y = jnp.einsum("bhpn,bn->bhp", h, Cm.astype(jnp.float32)[:, 0])
-    y = y[:, None] + xh.astype(jnp.float32) * params["d_skip"][None, None, :, None]
+        _per_head(Bm.astype(jnp.float32), H)[:, 0, :, None, :]
+    y = jnp.einsum("bhpn,bhn->bhp", h,
+                   _per_head(Cm.astype(jnp.float32), H)[:, 0])
+    y = _skip(params, y[:, None], xh)
     out = _mamba_finish(params, cfg, y.astype(x.dtype), z, B_, S)
     return out, {"h": h, "conv": conv_state}
+
+
+# ---------------------------------------------------------------------------
+# Mamba2 over state slots (the serving engine's prefill chunks and decode)
+# ---------------------------------------------------------------------------
+def state_slot_shapes(cfg: ModelConfig, n_layers: int, n_slots: int
+                      ) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+    """Shapes and dtypes of the state of ``n_layers`` Mamba layers for
+    ``n_slots`` requests: the SSM state in the kernels' layout
+    (``kernels/ssm_scan.pair_state``), kept in fp32, and the K-1
+    pre-activation conv inputs, in the model's dtype."""
+    from repro.kernels.ssm_scan import heads_per_row
+
+    d_inner, H, P, N = mamba_dims(cfg)
+    r = heads_per_row(H // cfg.ssm_groups, P)
+    return {"ssm": ((n_layers, n_slots, H // r, N, r * P), jnp.float32),
+            "conv": ((n_layers, n_slots, cfg.ssm_conv - 1,
+                      _conv_channels(cfg)), cfg.dtype)}
+
+
+def mamba_chunk(params: Dict, cfg: ModelConfig, x: jax.Array,
+                ssm0: Optional[jax.Array], conv0: Optional[jax.Array],
+                length: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One prefill chunk of one request from its state. x: (1, C, d)
+    normed input whose first ``length`` tokens (a traced count) are the
+    chunk's and the rest padding; ssm0: (H/r, N, rP) fp32 and conv0:
+    (K-1, conv_ch), or None where the request starts here. Returns (y
+    (1, C, d), ssm1, conv1): the chunk's output and the state after its
+    last token. A padded token takes no step (dt = 0: no decay, no
+    input), so the state is the real tokens' alone."""
+    from repro.kernels import ops as kops
+
+    B_, S, _ = x.shape
+    z, xh, Bm, Cm, dt, conv1 = _mamba_project(
+        params, cfg, x, None if conv0 is None else conv0[None], length)
+    dt = jnp.where((jnp.arange(S) < length)[None, :, None], dt, 0.0)
+    log_decay = -jnp.exp(params["a_log"]) * dt          # (1, C, H)
+    xdt = xh.astype(jnp.float32) * dt[..., None]
+    y, ssm1 = kops.ssm_chunk_scan(xdt, log_decay, Bm, Cm,
+                                  None if ssm0 is None else ssm0[None])
+    y = _skip(params, y, xh)
+    return (_mamba_finish(params, cfg, y.astype(x.dtype), z, B_, S),
+            ssm1[0], conv1[0])
+
+
+def mamba_decode_slots(params: Dict, cfg: ModelConfig, x: jax.Array,
+                       state: Dict, slots: jax.Array, layer: int
+                       ) -> Tuple[jax.Array, Dict]:
+    """One decode token per row, row b's state in slot ``slots[b]`` of
+    Mamba layer ``layer`` of ``state`` ({"ssm", "conv"}, as
+    ``state_slot_shapes``). x: (B, 1, d) normed input. The SSM state is
+    updated in place by ``ssm_decode_update``; returns (y (B, 1, d), the
+    new state)."""
+    from repro.kernels import ops as kops
+
+    B_, S, _ = x.shape
+    conv = state["conv"]
+    z, xh, Bm, Cm, dt, conv1 = _mamba_project(
+        params, cfg, x, conv[layer, slots])
+    decay = jnp.exp(-jnp.exp(params["a_log"]) * dt[:, 0])      # (B, H)
+    xdt = xh[:, 0].astype(jnp.float32) * dt[:, 0, :, None]
+    y, ssm = kops.ssm_decode_update(state["ssm"], slots, xdt, decay,
+                                    Bm[:, 0], Cm[:, 0], layer=layer)
+    y = _skip(params, y[:, None], xh)
+    out = _mamba_finish(params, cfg, y.astype(x.dtype), z, B_, S)
+    return out, {"ssm": ssm, "conv": conv.at[layer, slots].set(conv1)}
 
 
 # ===========================================================================

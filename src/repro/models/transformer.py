@@ -22,7 +22,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import blocks, ssm
-from repro.models.common import (ModelConfig, cross_entropy_loss, dense_init,
+from repro.models.attention import (attention_forward, init_attention,
+                                    out_project, qkv_project)
+from repro.models.common import (ATTENTION, MAMBA, MLP, ModelConfig,
+                                 cross_entropy_loss, dense_init, relu2_mlp,
                                  rms_norm, softcap)
 
 Params = Dict[str, Any]
@@ -79,6 +82,12 @@ def init_params(key, cfg: ModelConfig) -> Params:
             params["tail"] = _tree_stack_init(
                 keys[3], tail, lambda k: blocks.init_mamba_block(k, cfg))
         params["shared_attn"] = blocks.init_dense_block(keys[4], cfg)
+    elif fam == "mixed":  # one mixer per layer, by layer_pattern
+        params["layers"] = {
+            STACKS[kind]: _tree_stack_init(
+                keys[2 + i], len(cfg.layers_of(kind)),
+                lambda k, kind=kind: _init_mixed_layer(k, cfg, kind))
+            for i, kind in enumerate(STACKS) if cfg.layers_of(kind)}
     elif fam == "audio":  # seamless enc-dec
         enc_cfg = cfg
         params["enc_layers"] = _tree_stack_init(
@@ -91,6 +100,23 @@ def init_params(key, cfg: ModelConfig) -> Params:
     else:
         raise ValueError(f"unknown family {fam}")
     return params
+
+
+# a mixed stack's weights: one stack per layer kind, over its layers
+STACKS = {MAMBA: "mamba", MLP: "mlp", ATTENTION: "attention"}
+
+
+def _init_mixed_layer(key, cfg: ModelConfig, kind: str) -> Params:
+    p = {"norm": jnp.zeros((cfg.d_model,), cfg.dtype)}
+    if kind == MAMBA:
+        p["mamba"] = ssm.init_mamba(key, cfg)
+    elif kind == MLP:
+        ku, kd = jax.random.split(key)
+        p["w_up"] = dense_init(ku, (cfg.d_model, cfg.d_ff), cfg.dtype)
+        p["w_down"] = dense_init(kd, (cfg.d_ff, cfg.d_model), cfg.dtype)
+    else:
+        p["attn"] = init_attention(key, cfg)
+    return p
 
 
 def _zamba_split(cfg: ModelConfig) -> Tuple[int, int, int]:
@@ -641,6 +667,99 @@ def prefill_chunk(params: Params, cfg: ModelConfig, batch: Dict,
     cache = {"k": _hm(kv["k"], 2), "v": _hm(kv["v"], 2),
              "len": jnp.full((x.shape[0],), P + x.shape[1], jnp.int32)}
     return _head(params, cfg, x[:, -1]), cache
+
+
+def _mixed_layers(params: Params, cfg: ModelConfig):
+    """(kind, index among its kind's layers, that layer's weights) for
+    every layer of a mixed stack, in order."""
+    seen = {kind: 0 for kind in STACKS}
+    for kind in cfg.layer_pattern:
+        n = seen[kind]
+        seen[kind] += 1
+        yield kind, n, _tree_index(params["layers"][STACKS[kind]], n)
+
+
+def prefill_chunk_mixed(params: Params, cfg: ModelConfig, batch: Dict,
+                        k_pool: jax.Array, v_pool: jax.Array,
+                        prefix_blocks: jax.Array, state: Dict,
+                        slot: jax.Array, length: jax.Array, *,
+                        backend: str = "jnp"
+                        ) -> Tuple[jax.Array, Dict, Dict]:
+    """One prefill chunk of one request through a mixed stack (the
+    chunked-prefill contract of :func:`prefill_chunk`; B == 1), whose
+    first ``length`` tokens (a traced count) are the chunk's and the rest
+    padding, so that chunks of many lengths share a program. Attention
+    layers attend over the request's pool blocks ``prefix_blocks`` (the
+    pools hold the attention layers only, in order) and the chunk; Mamba
+    layers run from the request's state in slot ``slot`` of ``state``
+    ({"ssm", "conv"}: ``ssm.state_slot_shapes``), or from zeros where the
+    chunk is the request's first (no prefix blocks), and leave there the
+    state after the chunk's last token. Padding follows every real token,
+    so causal attention keeps it out of their results, and it takes no
+    Mamba step. Returns (the last real position's logits, KV {"k", "v",
+    "len"} with k/v (L_attn, 1, Hkv, C, hd), padding included, state)."""
+    if batch["tokens"].shape[0] != 1:
+        raise ValueError("chunked paged prefill is per-request (B == 1); "
+                         f"got B={batch['tokens'].shape[0]}")
+    P = prefix_blocks.shape[0] * k_pool.shape[3]
+    x, positions, _ = _embed(params, cfg, batch)
+    positions = positions + P
+    ks, vs = [], []
+    for kind, n, p in _mixed_layers(params, cfg):
+        h = rms_norm(x, p["norm"], cfg.norm_eps)
+        if kind == MAMBA:
+            first = P == 0
+            y, s1, c1 = ssm.mamba_chunk(
+                p["mamba"], cfg, h,
+                None if first else state["ssm"][n, slot],
+                None if first else state["conv"][n, slot], length)
+            state = {"ssm": state["ssm"].at[n, slot].set(s1),
+                     "conv": state["conv"].at[n, slot].set(c1)}
+        elif kind == MLP:
+            y = relu2_mlp(h, p["w_up"], p["w_down"])
+        else:
+            y, k, v = attention_forward(
+                p["attn"], cfg, h, positions, backend=backend,
+                paged_prefix=(k_pool[n], v_pool[n], prefix_blocks))
+            ks.append(k)
+            vs.append(v)
+        x = x + y
+    cache = {"k": _hm(jnp.stack(ks), 2), "v": _hm(jnp.stack(vs), 2),
+             "len": jnp.full((1,), P + length, jnp.int32)}
+    last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
+                                        keepdims=False)
+    return _head(params, cfg, last), cache, state
+
+
+def decode_step_mixed(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                      lens: jax.Array, state: Dict, slots: jax.Array,
+                      attend) -> Tuple[jax.Array, Dict]:
+    """One decode token per row through a mixed stack. Row b's Mamba state
+    is in slot ``slots[b]`` of ``state`` and is updated in place;
+    ``attend(n, q, k_new, v_new)`` is attention layer n's paged attention
+    over the stored tokens and the new one ((B, H, hd) queries; the
+    placement decides where it runs). ``lens``: tokens already stored.
+    Returns (logits, {"k_new", "v_new": (L_attn, B, Hkv, hd), "len",
+    "state"})."""
+    x, _, _ = _embed(params, cfg, {"tokens": tokens[:, None]})
+    ks, vs = [], []
+    for kind, n, p in _mixed_layers(params, cfg):
+        h = rms_norm(x, p["norm"], cfg.norm_eps)
+        if kind == MAMBA:
+            y, state = ssm.mamba_decode_slots(p["mamba"], cfg, h, state,
+                                              slots, n)
+        elif kind == MLP:
+            y = relu2_mlp(h, p["w_up"], p["w_down"])
+        else:
+            q, k, v = qkv_project(p["attn"], cfg, h, lens[:, None])
+            ks.append(k[:, 0])
+            vs.append(v[:, 0])
+            y = out_project(p["attn"], attend(n, q[:, 0], k[:, 0],
+                                              v[:, 0])[:, None])
+        x = x + y
+    updates = {"k_new": jnp.stack(ks), "v_new": jnp.stack(vs),
+               "len": lens + 1, "state": state}
+    return _head(params, cfg, x[:, 0]), updates
 
 
 def _hm(kv: jax.Array, seq_axis: int = 1) -> jax.Array:

@@ -41,6 +41,14 @@ from repro.serving.cluster.queues import (Handoff, HandoffError,
 HandoffSink = Callable[[Request, KVHandoffPayload], None]
 
 
+def _refuse_state_handoff(engine: LLMEngine) -> None:
+    if engine.kv.state is not None:
+        raise ValueError(
+            f"{engine.cfg.name} has recurrent (Mamba) layers: the prefill→"
+            f"decode handoff ships KV blocks only, and each request's "
+            f"recurrent state would have to travel with them")
+
+
 class PrefillEngine(LLMEngine):
     """Prefill-only role: admit, prefill, export, detach — never decode."""
 
@@ -49,6 +57,7 @@ class PrefillEngine(LLMEngine):
                  fault_injector=None, replica: int = 0, **overrides):
         super().__init__(cfg, params, engine_config,
                          fault_injector=fault_injector, **overrides)
+        _refuse_state_handoff(self)
         disagg = disagg or DisaggConfig(role="prefill")
         if disagg.role != "prefill":
             disagg = disagg.replace(role="prefill")
@@ -182,6 +191,7 @@ class DecodeEngine(LLMEngine):
                  fault_injector=None, replica: int = 0, **overrides):
         super().__init__(cfg, params, engine_config,
                          fault_injector=fault_injector, **overrides)
+        _refuse_state_handoff(self)
         disagg = disagg or DisaggConfig(role="decode")
         if disagg.role != "decode":
             disagg = disagg.replace(role="decode")

@@ -81,9 +81,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.models import kv_quant
-from repro.models.common import ModelConfig
+from repro.models import kv_quant, ssm
+from repro.models.common import MAMBA, ModelConfig
 
 # Base-position sentinel for table slots a shard does not own — the single
 # definition lives with the kernel; its numeric value is load-bearing for
@@ -135,6 +136,9 @@ class PagedKVCache:
     block_size: int = 16
     n_shards: int = 1
     kv_dtype: str = "bf16"             # "bf16" (cfg.dtype) | "int8"
+    # recurrent state slots (stacks with Mamba layers): one per request
+    # that can run at once
+    state_slots: int = 0
 
     def __post_init__(self):
         if self.num_blocks % self.n_shards:
@@ -146,7 +150,7 @@ class PagedKVCache:
             raise ValueError(f"kv_dtype must be 'bf16' or 'int8'; "
                              f"got {self.kv_dtype!r}")
         hd = self.cfg.resolved_head_dim
-        L = self._n_kv_layers()
+        L = self.cfg.num_kv_layers
         pool_dtype = jnp.int8 if self.kv_dtype == "int8" else self.cfg.dtype
         self.k_pool = jnp.zeros((L, self.cfg.num_kv_heads, self.num_blocks,
                                  self.block_size, hd), pool_dtype)
@@ -164,6 +168,25 @@ class PagedKVCache:
         else:
             self.k_scale = None
             self.v_scale = None
+        # Recurrent layers keep a state per request, not per token: one
+        # slot of every Mamba layer's (SSM, conv) state, taken with the
+        # sequence's first allocation and released with its table. The
+        # pools hold the attention layers only (cfg.num_kv_layers).
+        n_rec = len(self.cfg.layers_of(MAMBA))
+        self.state: Optional[Dict[str, jax.Array]] = None
+        self.slot_of: Dict[int, int] = {}
+        self._free_slots: List[int] = []
+        if n_rec:
+            if self.state_slots < 1:
+                raise ValueError(
+                    f"{self.cfg.name} has {n_rec} recurrent (Mamba) layers: "
+                    f"give the pool state_slots >= 1, one per request that "
+                    f"runs at once")
+            self.state = {
+                k: jnp.zeros(shape, dt) for k, (shape, dt) in
+                ssm.state_slot_shapes(self.cfg, n_rec,
+                                      self.state_slots).items()}
+            self._free_slots = list(range(self.state_slots))[::-1]
         npb = self.blocks_per_shard
         # per-shard free lists: shard s owns global ids [s·npb, (s+1)·npb)
         self._free_shard: List[List[int]] = [
@@ -283,11 +306,6 @@ class PagedKVCache:
                 raise OutOfBlocks("pool exhausted")
         return self._free_shard[target].pop()
 
-    def _n_kv_layers(self) -> int:
-        if self.cfg.family == "hybrid":
-            return self.cfg.num_layers // self.cfg.shared_attn_period
-        return self.cfg.num_layers
-
     # ---------------- allocation ----------------
     def blocks_needed(self, n_tokens: int) -> int:
         return -(-n_tokens // self.block_size)
@@ -346,6 +364,9 @@ class PagedKVCache:
                 f"{self._degraded_note()}",
                 rid=seq_id, live_tokens=sum(self.lengths.values()),
                 free_blocks=have, **self._degraded_kw())
+        if self.state is not None and not self._free_slots:
+            raise OutOfBlocks(f"allocating seq {seq_id}: every one of the "
+                              f"{self.state_slots} state slots is taken")
         # round-robin over shards: the sequence's i-th block lands on shard
         # i mod n_shards, so its KV spans every pool chip near-evenly
         table = [self._pop_block(i) for i in range(need)]
@@ -353,6 +374,9 @@ class PagedKVCache:
             self.refcounts[b] = 1
         self.tables[seq_id] = table
         self.lengths[seq_id] = n_tokens
+        if self.state is not None:
+            with TraceAnnotation("engine.state"):
+                self.slot_of[seq_id] = self._free_slots.pop()
 
     def share_blocks(self, src_rid: int, dst_rid: int, n_tokens: int) -> int:
         """Map a NEW sequence `dst_rid`'s table onto `src_rid`'s existing
@@ -444,6 +468,9 @@ class PagedKVCache:
                 self._free_shard[self.shard_of(b)].append(b)
         self._borrowed.pop(seq_id, None)
         del self.lengths[seq_id]
+        if seq_id in self.slot_of:
+            with TraceAnnotation("engine.state"):
+                self._free_slots.append(self.slot_of.pop(seq_id))
 
     @property
     def used_blocks(self) -> int:
@@ -461,6 +488,26 @@ class PagedKVCache:
         if self.k_scale is not None:
             total += int(self.k_scale.nbytes + self.v_scale.nbytes)
         return total
+
+    # ---------------- recurrent state slots ----------------
+    @property
+    def has_free_slot(self) -> bool:
+        """A new sequence can take a state slot (always, without recurrent
+        layers)."""
+        return self.state is None or bool(self._free_slots)
+
+    @property
+    def state_bytes_resident(self) -> int:
+        """Bytes of every state slot of every recurrent layer: the SSM
+        state (fp32) and the conv inputs."""
+        if self.state is None:
+            return 0
+        return int(sum(a.nbytes for a in self.state.values()))
+
+    def slot_indices(self, seq_ids: Sequence[int]) -> np.ndarray:
+        """(B,) int32 state slots of a batch's sequences, in order."""
+        with TraceAnnotation("engine.state"):
+            return np.asarray([self.slot_of[s] for s in seq_ids], np.int32)
 
     def bytes_per_live_token(self) -> int:
         """Pool bytes one token of context occupies (K + V across the KV
@@ -496,13 +543,16 @@ class PagedKVCache:
         return sum(per_block.values())
 
     # ---------------- hot-path views ----------------
-    def block_table_batch(self, seq_ids: Sequence[int]
+    def block_table_batch(self, seq_ids: Sequence[int], width: int = 1
                           ) -> Tuple[np.ndarray, np.ndarray]:
         """Batched (B, nb) block table + (B,) lengths for the paged decode
-        step. nb covers the longest live sequence; pad slots are block 0
-        (their positions are ≥ cache_len, so the kernel masks them)."""
+        step. nb covers the longest live sequence, rounded up to a
+        multiple of ``width`` (fewer distinct shapes, so fewer decode
+        programs); pad slots are block 0 (their positions are ≥
+        cache_len, so the kernel masks them)."""
         lens = np.array([self.lengths[sid] for sid in seq_ids], np.int32)
         nb = max(1, self.blocks_needed(int(lens.max()))) if len(lens) else 1
+        nb = -(-nb // width) * width
         tables = np.zeros((len(seq_ids), nb), np.int32)
         for i, sid in enumerate(seq_ids):
             t = self.tables[sid][:nb]

@@ -88,7 +88,10 @@ time to the innermost ``engine.*`` span open over it:
     one-shot and suffix prefill) with its host-to-device inputs and, for
     decode, the wait for its result and its finiteness check;
   * ``engine.sample`` — ``_sample`` and the transfer of the sampled tokens
-    into ``Request.record_token``.
+    into ``Request.record_token``;
+  * ``engine.state`` — a stack with recurrent layers' state slots: taking
+    a slot at admission and freeing it at retirement (in
+    ``PagedKVCache``), and the slot indices of a decode step or chunk.
 
 Spans nest (a one-shot prefill inside admission is ``engine.model``
 inside ``engine.sched``) and close when the step raises.
@@ -112,8 +115,34 @@ from repro.serving.faults import DEAD, FaultInjector, ShardHealthTracker
 from repro.serving.kvcache import PagedKVCache, PoolExhausted
 from repro.serving.placement import PlacementStrategy, make_placement
 from repro.serving.request import Request, SamplingParams, State
-from repro.serving.sampler import request_key, sample_per_request
+from repro.serving.sampler import sample_requests
 from repro.serving.scheduler import RequestScheduler, make_policy
+
+
+# a mixed stack's programs are large, so its shapes are rounded: the
+# decode batch's block tables to multiples of TABLE_WIDTH blocks (1024
+# tokens at the benchmark's block size), a prefill chunk's tokens to
+# multiples of CHUNK_STEP (the Mamba-2 scan's sub-chunk)
+TABLE_WIDTH = 64
+CHUNK_STEP = 128
+
+
+def _refuse_recurrent(cfg: ModelConfig, econf: EngineConfig,
+                      fault_injector) -> None:
+    """What an engine cannot do yet where layers keep a recurrent state
+    (one slot per request, updated in place) beside the KV pool."""
+    why = None
+    if econf.prefix_sharing:
+        why = ("prefix sharing maps a prompt onto a donor's KV blocks, but "
+               "the recurrent state after the shared prefix is not kept "
+               "(it would need a state snapshot per shared block)")
+    elif econf.kv_dtype != "bf16":
+        why = f"a {econf.kv_dtype} KV pool is not wired to the mixed stack"
+    elif fault_injector is not None:
+        why = ("fault injection retries a decode step, and a retry would "
+               "apply the in-place state update twice")
+    if why:
+        raise ValueError(f"{cfg.name} has recurrent (Mamba) layers: {why}")
 
 
 class SchedulingStalled(RuntimeError):
@@ -212,9 +241,9 @@ class LLMEngine:
         (``serving/faults.py``) at the pool boundary; the health machine
         and recovery paths are always live — the injector only supplies
         the faults."""
-        if cfg.family not in ("dense", "moe", "vlm"):
-            raise ValueError("engine serves KV-cache architectures; "
-                             f"got family={cfg.family}")
+        if cfg.family not in ("dense", "moe", "vlm", "mixed"):
+            raise ValueError("engine serves KV-cache architectures and "
+                             f"mixed stacks; got family={cfg.family}")
         econf = engine_config or EngineConfig()
         if overrides:
             econf = econf.replace(**overrides)
@@ -223,7 +252,12 @@ class LLMEngine:
         self.params = params
         self.kv = PagedKVCache(cfg, econf.num_blocks, econf.block_size,
                                n_shards=econf.resolved_kv_shards,
-                               kv_dtype=econf.kv_dtype)
+                               kv_dtype=econf.kv_dtype,
+                               state_slots=econf.max_batch)
+        recurrent = self.kv.state is not None
+        if recurrent:
+            _refuse_recurrent(cfg, econf, fault_injector)
+        self._table_width = TABLE_WIDTH if recurrent else 1
         self.placement: PlacementStrategy = make_placement(cfg, econf)
         # Chunked prefill is a COMPUTE decision like the prefix-sharing
         # skip: a chunk boundary changes MoE capacity-dispatch groups, so
@@ -239,7 +273,10 @@ class LLMEngine:
                                       prefix_sharing=econf.prefix_sharing)
         self.stats = EngineStats()
         self.stats.kv_pool_bytes_resident = self.kv.pool_bytes_resident
-        self._decode_jit = jax.jit(self.placement.decode_fn())
+        self.stats.state_bytes_resident = self.kv.state_bytes_resident
+        # a recurrent state is updated in place: the steps take it donated
+        donate = {"donate_argnames": ("state",)} if recurrent else {}
+        self._decode_jit = jax.jit(self.placement.decode_fn(), **donate)
 
         def prefill(p, b):
             return transformer.prefill(p, cfg, b,
@@ -279,7 +316,14 @@ class LLMEngine:
             return transformer.prefill_chunk(
                 p, cfg, b, kp, vp, idx, backend=econf.decode_backend,
                 k_scale_pool=ks, v_scale_pool=vs)
-        self._prefill_chunk_jit = jax.jit(prefill_chunk)
+
+        def prefill_chunk_mixed(p, b, kp, vp, idx, state, slot, length):
+            return transformer.prefill_chunk_mixed(
+                p, cfg, b, kp, vp, idx, state, slot, length,
+                backend=econf.decode_backend)
+        self._prefill_chunk_jit = (
+            jax.jit(prefill_chunk_mixed, **donate) if recurrent
+            else jax.jit(prefill_chunk))
         # the programs whose new executables make a step a compile step,
         # held apart from the attributes so that a wrapper put in their
         # place still counts the program it wraps
@@ -398,6 +442,8 @@ class LLMEngine:
     def _admission_wave(self) -> None:
         while True:
             admitted = self.sched.admit()
+            self.stats.state_slots_peak = max(self.stats.state_slots_peak,
+                                              len(self.kv.slot_of))
             for req in admitted:
                 if self.sched.prefill_cursor(req.rid) is not None:
                     # chunked admission: only the first chunk's blocks were
@@ -608,7 +654,11 @@ class LLMEngine:
         """Refuse to sample from non-finite logits (satellite guard — live
         with or without an injector): name the offending requests and the
         engine step instead of silently emitting garbage tokens."""
-        finite = np.asarray(jnp.isfinite(logits).all(axis=-1))
+        self._refuse_non_finite(reqs,
+                                np.asarray(jnp.isfinite(logits).all(axis=-1)))
+
+    def _refuse_non_finite(self, reqs: List[Request],
+                           finite: np.ndarray) -> None:
         if bool(finite.all()):
             return
         bad = [r.rid for r, ok in zip(reqs, finite) if not ok]
@@ -687,8 +737,13 @@ class LLMEngine:
         self.stats.max_prefill_slab_tokens = max(
             self.stats.max_prefill_slab_tokens, len(known))
         with TraceAnnotation("engine.model"):
-            toks = jnp.asarray([list(known)], jnp.int32)
-            logits, cache = self._prefill_jit(self.params, {"tokens": toks})
+            if self.kv.state is None:
+                toks = jnp.asarray([list(known)], jnp.int32)
+                logits, cache = self._prefill_jit(self.params,
+                                                  {"tokens": toks})
+            else:   # recurrent layers: the whole of `known` as one chunk
+                logits, cache = self._run_chunk(
+                    rid, list(known), self.kv.gather_prefix_indices(rid, 0))
         # cache k/v are head-major (L, 1, Hkv, S, hd) — the pool's layout
         with TraceAnnotation("engine.pool"):
             self.kv.write_prefill(rid, cache["k"][:, 0, :, shared:],
@@ -748,10 +803,8 @@ class LLMEngine:
         with TraceAnnotation("engine.pool"):
             idx = self.kv.gather_prefix_indices(rid, cursor)
         with TraceAnnotation("engine.model"):
-            toks = jnp.asarray([list(known[cursor:target])], jnp.int32)
-            logits, cache = self._prefill_chunk_jit(
-                self.params, {"tokens": toks}, self.kv.k_pool,
-                self.kv.v_pool, idx, **self._scale_kwargs("ks", "vs"))
+            logits, cache = self._run_chunk(rid, list(known[cursor:target]),
+                                            idx)
         with TraceAnnotation("engine.pool"):
             # chunk cache k/v are head-major (L, 1, Hkv, C, hd) — the
             # pool's layout; write_prefill_chunk extends the allocation
@@ -770,6 +823,27 @@ class LLMEngine:
             # last chunk's last position seeds sampling — same contract as
             # the one-shot prefill (TTFT lands here)
             self._sample_and_record([req], logits)
+
+    def _run_chunk(self, rid: int, tokens: List[int], idx: jax.Array):
+        """The jitted prefill-chunk program for ``tokens`` over ``idx``,
+        the request's pool blocks before them. With recurrent layers it
+        also reads the request's state slot (a fresh state where ``idx``
+        is empty) and leaves there the state after the chunk; the tokens
+        are padded to a multiple of CHUNK_STEP, so that chunks of many
+        lengths share a program, and the padding's KV is cut off."""
+        if self.kv.state is None:
+            return self._prefill_chunk_jit(
+                self.params, {"tokens": jnp.asarray([tokens], jnp.int32)},
+                self.kv.k_pool, self.kv.v_pool, idx,
+                **self._scale_kwargs("ks", "vs"))
+        n = len(tokens)
+        toks = jnp.asarray([tokens + [0] * (-n % CHUNK_STEP)], jnp.int32)
+        with TraceAnnotation("engine.state"):
+            slot = np.int32(self.kv.slot_of[rid])
+        logits, cache, self.kv.state = self._prefill_chunk_jit(
+            self.params, {"tokens": toks}, self.kv.k_pool, self.kv.v_pool,
+            idx, state=self.kv.state, slot=slot, length=np.int32(n))
+        return logits, {k: cache[k][:, :, :, :n] for k in ("k", "v")}
 
     def _free_blocks_for_chunk(self, req: Request, need: int) -> bool:
         """Check `need` blocks are free before a chunk allocation. Chunk
@@ -827,11 +901,15 @@ class LLMEngine:
             # placement-specific per-iteration operands + per-worker
             # accounting
             extra = self.placement.decode_extra_args(self.kv, ids, live)
-            tables, lens = self.kv.block_table_batch(ids)
+            tables, lens = self.kv.block_table_batch(ids, self._table_width)
+        slots = None
+        if self.kv.state is not None:
+            with TraceAnnotation("engine.state"):
+                slots = jnp.asarray(self.kv.slot_indices(ids))
         with TraceAnnotation("engine.model"):
             tokens = jnp.asarray([r.output[-1] for r in running], jnp.int32)
             out = self._decode_validated(running, tokens, tables, lens,
-                                         extra)
+                                         extra, slots)
         if out is None:
             # a shard died mid-decode: this iteration is aborted with
             # NOTHING committed (no append, no pool write, no sample) —
@@ -857,7 +935,7 @@ class LLMEngine:
         self._sample_and_record(running, logits)
 
     def _decode_validated(self, running: List[Request], tokens, tables,
-                          lens, extra):
+                          lens, extra, slots=None):
         """Run the jitted decode step and VALIDATE the merged output
         before anything is committed (no token append, no pool write, no
         sampling has happened yet). Injected corruption — NaN partials
@@ -868,14 +946,22 @@ class LLMEngine:
         retry budget declare the shard dead (returns ``None`` — the
         caller aborts the iteration; victims were already evicted).
         Non-finite logits NO fault accounts for raise
-        :class:`CorruptedLogitsError`."""
+        :class:`CorruptedLogitsError`.
+
+        With recurrent layers (``slots`` given) the step updates their
+        state in place, and the engine takes the new state at once: such
+        an engine takes no fault injector, so the step is never retried."""
         attempt = 0
         suspect = None
         while True:
+            kw = (self._scale_kwargs("k_scale_pool", "v_scale_pool")
+                  if slots is None else
+                  {"state": self.kv.state, "slots": slots})
             logits, updates = self._decode_jit(
                 self.params, tokens, self.kv.k_pool, self.kv.v_pool,
-                jnp.asarray(tables), jnp.asarray(lens), *extra,
-                **self._scale_kwargs("k_scale_pool", "v_scale_pool"))
+                jnp.asarray(tables), jnp.asarray(lens), *extra, **kw)
+            if slots is not None:
+                self.kv.state = updates.pop("state")
             logits.block_until_ready()
             shard = None
             if self._fault is not None:
@@ -954,25 +1040,24 @@ class LLMEngine:
     def _sample_and_record(self, reqs: List[Request],
                            logits: jax.Array) -> None:
         with TraceAnnotation("engine.sample"):
-            toks = self._sample(reqs, logits)
-            for i, r in enumerate(reqs):
-                r.record_token(int(toks[i]))
+            toks = np.asarray(self._sample(reqs, logits))
+            for r, tok in zip(reqs, toks.tolist()):
+                r.record_token(tok)
         self._tokens_out += len(reqs)
 
-    def _sample(self, reqs: List[Request], logits: jax.Array) -> jax.Array:
-        self._guard_finite(reqs, logits)
-        keys = jnp.stack([self._request_key(r) for r in reqs])
-        temps = np.asarray([r.params.temperature for r in reqs], np.float32)
-        topks = np.asarray([r.params.top_k for r in reqs], np.int32)
-        return sample_per_request(logits, keys, temps, topks)
-
-    def _request_key(self, req: Request) -> jax.Array:
-        # token i of this request always draws from stream index i, via the
+    def _sample(self, reqs: List[Request], logits: jax.Array) -> np.ndarray:
+        # token i of a request always draws from stream index i, via the
         # one canonical seed→stream mapping (sampler.request_key); a request
         # without its own seed falls back to the engine's
-        seed = req.params.seed
-        return request_key(self.config.seed if seed is None else seed,
-                           len(req.output))
+        toks, finite = sample_requests(
+            logits,
+            [self.config.seed if r.params.seed is None else r.params.seed
+             for r in reqs],
+            [len(r.output) for r in reqs],
+            [r.params.temperature for r in reqs],
+            [r.params.top_k for r in reqs])
+        self._refuse_non_finite(reqs, finite)
+        return toks
 
     # ------------------------------------------------------------------
     # introspection (CLI / benchmarks)

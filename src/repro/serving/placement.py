@@ -30,7 +30,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import transformer
-from repro.models.attention import out_project, qkv_project
+from repro.models.attention import (out_project,
+                                    paged_decode_attention_combine,
+                                    qkv_project)
 from repro.models.common import ModelConfig, rms_norm
 from repro.models.ffn import ffn_forward
 from repro.models.moe import moe_forward
@@ -160,6 +162,16 @@ class HomogeneousPlacement(PlacementStrategy):
 
     def decode_fn(self):
         cfg, backend = self.cfg, self.econf.decode_backend
+        if cfg.family == "mixed":
+            def mixed_step(params, tokens, k_pool, v_pool, block_tables,
+                           lens, *, state, slots):
+                def attend(n, q, k_new, v_new):
+                    return paged_decode_attention_combine(
+                        q, k_pool[n], v_pool[n], block_tables, lens, k_new,
+                        v_new, backend=backend)
+                return transformer.decode_step_mixed(
+                    params, cfg, tokens, lens, state, slots, attend)
+            return mixed_step
 
         def step(params, tokens, k_pool, v_pool, block_tables, lens,
                  k_scale_pool=None, v_scale_pool=None):
@@ -188,6 +200,21 @@ class AttentionPoolPlacement(PlacementStrategy):
 
     def decode_fn(self):
         cfg, pool = self.cfg, self._pool
+        if cfg.family == "mixed":
+            def mixed_step(params, tokens, k_pool, v_pool, block_tables,
+                           lens, shard_tables=None, shard_positions=None, *,
+                           state, slots):
+                # the model worker runs the Mamba and MLP layers; each
+                # attention layer's query goes to the pool, which reads
+                # its slice of the paged pool in place
+                def attend(n, q, k_new, v_new):
+                    return pool.attend_paged(
+                        q, k_pool[n], v_pool[n], block_tables, lens, k_new,
+                        v_new, shard_tables=shard_tables,
+                        shard_positions=shard_positions)
+                return transformer.decode_step_mixed(
+                    params, cfg, tokens, lens, state, slots, attend)
+            return mixed_step
 
         def step(params, tokens, k_pool, v_pool, block_tables, lens,
                  shard_tables=None, shard_positions=None,
@@ -205,7 +232,7 @@ class AttentionPoolPlacement(PlacementStrategy):
         host-side — the jitted step's python body fires at trace time only)
         plus, for the block partition, the compacted per-shard local tables
         that let each worker walk only its ~1/n of the live blocks."""
-        pool, L = self._pool, self.cfg.num_layers
+        pool, L = self._pool, self.cfg.num_kv_layers
         if pool.partition == "block":
             # one table walk serves both the jitted step's compacted shard
             # tables and the live-token accounting
@@ -237,8 +264,8 @@ class AttentionPoolPlacement(PlacementStrategy):
         hd = cfg.resolved_head_dim
         per_head = hd + 4 if self.econf.kv_dtype == "int8" else hd * BYTES
         self._pool.log.kv_bytes += (2 * tokens * cfg.num_kv_heads *
-                                    per_head * cfg.num_layers)
-        self._pool.log.transfers += cfg.num_layers
+                                    per_head * cfg.num_kv_layers)
+        self._pool.log.transfers += cfg.num_kv_layers
 
 
 class MoEOffloadPlacement(AttentionPoolPlacement):

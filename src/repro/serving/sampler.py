@@ -3,8 +3,12 @@ per-request variants (a continuous batch mixes every request's own
 SamplingParams in one decode iteration)."""
 from __future__ import annotations
 
+import functools
+from typing import Sequence, Tuple
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def sample(logits: jax.Array, key, temperature: float = 0.0,
@@ -27,6 +31,11 @@ def request_key(seed: int, token_index: int) -> jax.Array:
     independent of batch composition, admission order, or preemption, so
     identical requests reproduce identically wherever they run."""
     return jax.random.fold_in(jax.random.PRNGKey(seed), token_index)
+
+
+@functools.lru_cache(maxsize=4096)
+def _seed_key(seed: int) -> np.ndarray:
+    return np.asarray(jax.random.PRNGKey(seed))
 
 
 def _scale_and_mask(logits: jax.Array, temperatures, top_ks):
@@ -76,3 +85,28 @@ def sample_batch(logits: jax.Array, key, temperatures: jax.Array,
     greedy, scaled, temps = _scale_and_mask(logits, temperatures, top_ks)
     drawn = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(temps <= 0.0, greedy, drawn)
+
+
+@jax.jit
+def _sample_rows(logits, seed_keys, token_indices, temperatures, top_ks):
+    keys = jax.vmap(jax.random.fold_in)(seed_keys, token_indices)
+    toks = jax.lax.cond(
+        jnp.all(temperatures <= 0.0),
+        lambda: jnp.argmax(logits.astype(jnp.float32),
+                           axis=-1).astype(jnp.int32),
+        lambda: sample_per_request(logits, keys, temperatures, top_ks))
+    return toks, jnp.isfinite(logits).all(axis=-1)
+
+
+def sample_requests(logits: jax.Array, seeds: Sequence[int],
+                    token_indices: Sequence[int], temperatures,
+                    top_ks) -> Tuple[np.ndarray, np.ndarray]:
+    """:func:`sample_per_request` with row i's key ``request_key(seeds[i],
+    token_indices[i])``, as one jitted program and one transfer to the
+    host. A batch of greedy rows skips the top-k sort. Returns the (B,)
+    int32 tokens and, per row, whether all its logits are finite."""
+    seed_keys = np.stack([_seed_key(int(s)) for s in seeds])
+    toks, finite = _sample_rows(
+        logits, seed_keys, np.asarray(token_indices, np.uint32),
+        np.asarray(temperatures, np.float32), np.asarray(top_ks, np.int32))
+    return jax.device_get((toks, finite))

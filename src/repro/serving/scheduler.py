@@ -313,7 +313,8 @@ class RequestScheduler:
 
     def admit(self) -> List[Request]:
         """FCFS-prefix admission: move waiting requests to running while the
-        pool can hold their stored tokens + decode headroom. The head of the
+        pool can hold their stored tokens + decode headroom (and, with
+        recurrent layers, a state slot is free). The head of the
         queue blocks the tail (head-of-line blocking is the documented FCFS
         trade-off — a size-aware policy can override this hook). With prefix
         sharing, only the unshared suffix is charged against the pool."""
@@ -344,7 +345,8 @@ class RequestScheduler:
                     break
             else:
                 first = stored - shared
-            if not self.kv.can_allocate(first + self.decode_headroom):
+            if not (self.kv.can_allocate(first + self.decode_headroom)
+                    and self.kv.has_free_slot):
                 break
             self.waiting.pop(0)
             if shared:

@@ -80,6 +80,11 @@ class EngineStats:
     # bytes per token-head vs 2·hd), the reduction bench_serving asserts
     kv_pool_bytes_resident: int = 0
     kv_bytes_read: int = 0
+    # recurrent layers' state slots (mixed stacks with Mamba layers): the
+    # most slots in use at once, and the bytes of every slot (fp32 SSM
+    # state and conv inputs), resident whether a slot is in use or not
+    state_slots_peak: int = 0
+    state_bytes_resident: int = 0
 
     @property
     def mean_batch(self) -> float:
@@ -166,6 +171,8 @@ class EngineStats:
             "kv_bytes_transferred": self.kv_bytes_transferred,
             "kv_pool_bytes_resident": self.kv_pool_bytes_resident,
             "kv_bytes_read_per_step": self.kv_bytes_read_per_step,
+            "state_slots_peak": self.state_slots_peak,
+            "state_bytes_resident": self.state_bytes_resident,
             "handoffs_completed": self.handoffs_completed,
             "handoff_retries": self.handoff_retries,
             "router_affinity_hits": self.router_affinity_hits,

@@ -84,7 +84,7 @@ class AttentionWorkerPool:
         """Shape-derived per-iteration accounting (jit-safe path)."""
         cfg = self.cfg
         hd = cfg.resolved_head_dim
-        L = cfg.num_layers
+        L = cfg.num_kv_layers
         self.log.q_bytes += batch * cfg.num_heads * hd * BYTES * L
         self.log.kv_bytes += 2 * batch * cfg.num_kv_heads * hd * BYTES * L
         self.log.out_bytes += batch * cfg.num_heads * hd * BYTES * L
@@ -280,7 +280,7 @@ class AttentionWorkerPool:
 def expected_transfer_bytes(cfg: ModelConfig, batch: int) -> int:
     """Paper §3.1: (2 + 2/G)·e·d_q·B·L per iteration."""
     G = cfg.gqa_group
-    return int((2 + 2 / G) * BYTES * cfg.q_dim * batch * cfg.num_layers)
+    return int((2 + 2 / G) * BYTES * cfg.q_dim * batch * cfg.num_kv_layers)
 
 
 def transfer_bytes_moe(cfg: ModelConfig, batch: int) -> int:

@@ -3,12 +3,14 @@ scheduler FCFS + memory safety, request lifecycle."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import registry
 from repro.serving.kvcache import PagedKVCache
 from repro.serving.request import Request, SamplingParams, State
-from repro.serving.sampler import sample
+from repro.serving.sampler import (request_key, sample, sample_per_request,
+                                   sample_requests)
 from repro.serving.scheduler import RequestScheduler
 
 
@@ -37,6 +39,26 @@ def test_top_k_restricts_support():
         key, sub = jax.random.split(key)
         seen.add(int(sample(logits, sub, temperature=1.0, top_k=2)[0]))
     assert seen <= {0, 1}
+
+
+@pytest.mark.parametrize("temps", [(0.0, 0.0, 0.0, 0.0),
+                                   (0.0, 0.7, 1.0, 1.3)])
+def test_one_program_sampler_draws_the_per_row_keys(temps):
+    """The engine's one-program sampler draws each row from
+    request_key(seed, token index), exactly as the per-row keys do, and
+    reports each row's finiteness."""
+    rng = np.random.default_rng(3)
+    logits = jnp.asarray(rng.normal(size=(4, 97)) * 3, jnp.bfloat16)
+    seeds, idx, topks = [0, 2**31 + 5, 7, 0], [0, 3, 511, 12], [0, 5, 0, 1]
+    keys = jnp.stack([request_key(s, i) for s, i in zip(seeds, idx)])
+    want = sample_per_request(logits, keys, np.float32(temps),
+                              np.int32(topks))
+    toks, finite = sample_requests(logits, seeds, idx, temps, topks)
+    assert toks.dtype == np.int32 and toks.tolist() == want.tolist()
+    assert finite.tolist() == [True] * 4
+    _, finite = sample_requests(logits.at[2, 5].set(jnp.nan), seeds, idx,
+                                temps, topks)
+    assert finite.tolist() == [True, True, False, True]
 
 
 @settings(deadline=None, max_examples=25)

@@ -6,10 +6,13 @@ cannot lay out. These tests compile the four served kernel variants (paged
 decode and prefill-chunk, bf16 and int8 pools) at the served models'
 widths for a described ``v5e:2x2`` topology, with no chip attached, the
 decode kernel at the benchmark cell's size, and the cross-chip head and
-block splits over its four devices.
+block splits over its four devices; and the two Mamba-2 kernels (the
+decode step's in-place state update and the prefill chunk's scan) at the
+``nemotron-h-47b`` cell's widths.
 Each asserts that the compiled program holds the Mosaic kernel, under the
 instruction name that the device trace shows and the benchmark's roofline
-readers match (``paged_decode`` for decode, ``paged_prefill`` for chunks).
+readers match (``paged_decode`` for decode, ``paged_prefill`` for chunks,
+``ssm_decode`` and ``ssm_chunk_scan`` for the Mamba-2 state).
 
 The topology is described inside a fixture: only one process may load the
 TPU compiler's library at a time, so nothing here may touch it while the
@@ -66,7 +69,11 @@ def topo():
 
 
 # instruction-name substrings the benchmark's roofline readers match
-KERNEL_NAMES = {"decode": "paged_decode", "chunk": "paged_prefill"}
+KERNEL_NAMES = {"decode": "paged_decode", "chunk": "paged_prefill",
+                "ssm_decode": "ssm_decode", "ssm_scan": "ssm_chunk_scan"}
+# the nemotron-h-47b cell's Mamba-2 layers: 64 heads of 64 in 2 groups,
+# state 256, 9 layers' slots for 16 rows, 512-token prefill chunks
+SSM_H, SSM_P, SSM_G, SSM_N, SSM_L, SSM_B, SSM_C = 64, 64, 2, 256, 9, 16, 512
 
 
 def _compiled_text(fn, *args) -> str:
@@ -202,3 +209,45 @@ def test_paged_split_compiles_for_four_v5e_chips(topo, split):
         args = (q, kp, kp, local, local, cl)
     names = _kernel_instructions(_compiled_text(fn, *args))
     assert names and all(KERNEL_NAMES["decode"] in n for n in names), names
+
+
+@pytest.mark.parametrize("kernel", ["ssm_decode", "ssm_scan"])
+def test_ssm_kernel_compiles_for_v5e_at_cell_size(topo, kernel):
+    """The decode step's state update under a jit that donates the state
+    writes it in place: the program aliases the whole fp32 state and holds
+    no temporary buffer a hundredth its size. The chunk scan, from a given
+    state, over a 512-token chunk."""
+    from repro.kernels.ssm_decode_update import ssm_decode_update
+    from repro.kernels.ssm_scan import heads_per_row, ssm_chunk_scan
+
+    one = SingleDeviceSharding(topo.devices[0])
+    f32 = jnp.float32
+
+    def sds(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    H, P, G, N = SSM_H, SSM_P, SSM_G, SSM_N
+    r = heads_per_row(H // G, P)
+    if kernel == "ssm_decode":
+        state = sds((SSM_L, SSM_B, H // r, N, r * P))
+
+        def fn(st, sl, x, d, b, c):
+            return ssm_decode_update(st, sl, x, d, b, c, layer=SSM_L - 1)
+        compiled = jax.jit(fn, donate_argnums=0).lower(
+            state, sds((SSM_B,), jnp.int32), sds((SSM_B, H, P)),
+            sds((SSM_B, H)), sds((SSM_B, G, N)), sds((SSM_B, G, N))
+        ).compile()
+        mem = compiled.memory_analysis()
+        state_bytes = SSM_L * SSM_B * H * P * N * 4
+        assert mem.alias_size_in_bytes == state_bytes
+        assert mem.temp_size_in_bytes < state_bytes / 100
+        text = compiled.as_text()
+    else:
+        def fn(x, a, b, c, s0):
+            return ssm_chunk_scan(x, a, b, c, s0)
+        text = _compiled_text(
+            fn, sds((1, SSM_C, H, P)), sds((1, SSM_C, H)),
+            sds((1, SSM_C, G, N)), sds((1, SSM_C, G, N)),
+            sds((1, H // r, N, r * P)))
+    names = _kernel_instructions(text)
+    assert names and all(KERNEL_NAMES[kernel] in n for n in names), names
